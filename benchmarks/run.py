@@ -16,6 +16,7 @@ import sys
 import time
 
 from benchmarks.common import validate_bench_files
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = ("figure1", "table2", "table3", "table4", "figure3",
            "table6_suite", "table7_bmw", "table8_qlen", "dense_transfer",
@@ -27,6 +28,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     mods = args.only or MODULES
     print("name,us_per_call,derived")
 

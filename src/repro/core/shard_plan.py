@@ -35,6 +35,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from .index import BlockedImpactIndex
 
@@ -225,3 +227,31 @@ def shard_index(index, n_shards: int) -> ShardedImpactIndex:
         tile_max_l=jnp.asarray(np.stack(tml_l)),
         sigma_b=index.sigma_b, sigma_l=index.sigma_l,
         orig_of_new=index.orig_of_new)
+
+
+def place_on_mesh(sharded: ShardedImpactIndex, mesh,
+                  axis_name: str = "shard") -> ShardedImpactIndex:
+    """Lay a stacked shard index out over a one-axis mesh: shard ``s`` of
+    every stacked leaf lives on device ``s`` and the global per-term
+    maxima are replicated. Done once when the index is opened, so no
+    search call moves index bytes between devices."""
+    if mesh.shape[axis_name] != sharded.n_shards:
+        raise ValueError(
+            f"mesh axis {axis_name!r} has size {mesh.shape[axis_name]} but "
+            f"the index has {sharded.n_shards} shards")
+
+    def stacked(a):
+        spec = P(axis_name, *([None] * (a.ndim - 1)))
+        return jax.device_put(a, NamedSharding(mesh, spec))
+
+    def replicated(a):
+        return jax.device_put(a, NamedSharding(mesh, P()))
+
+    return dataclasses.replace(
+        sharded, gather=tuple(stacked(a) for a in sharded.gather),
+        doc_base=stacked(sharded.doc_base),
+        n_real_tiles=stacked(sharded.n_real_tiles),
+        tile_max_b=stacked(sharded.tile_max_b),
+        tile_max_l=stacked(sharded.tile_max_l),
+        sigma_b=replicated(sharded.sigma_b),
+        sigma_l=replicated(sharded.sigma_l))

@@ -22,7 +22,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -69,9 +68,9 @@ def ring_all_reduce(x, mesh, axis_name: str):
     1-device mesh this is the identity.
     """
     n = mesh.shape[axis_name]
-    f = shard_map(partial(_ring_sum, axis_name=axis_name, n=n), mesh=mesh,
-                  in_specs=_shard_spec(x.ndim, axis_name),
-                  out_specs=P(*([None] * x.ndim)), check_rep=False)
+    f = jax.shard_map(partial(_ring_sum, axis_name=axis_name, n=n),
+                      mesh=mesh, in_specs=_shard_spec(x.ndim, axis_name),
+                      out_specs=P(*([None] * x.ndim)), check_vma=False)
     return f(x)
 
 
@@ -85,9 +84,9 @@ def hierarchical_all_reduce(x, mesh, inner_axis: str, outer_axis: str):
         y = _ring_sum(local, inner_axis, n_in)
         return _ring_sum(y, outer_axis, n_out)
 
-    return shard_map(f, mesh=mesh,
-                     in_specs=_shard_spec(x.ndim, (outer_axis, inner_axis)),
-                     out_specs=P(*([None] * x.ndim)), check_rep=False)(x)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=_shard_spec(x.ndim, (outer_axis, inner_axis)),
+                         out_specs=P(*([None] * x.ndim)), check_vma=False)(x)
 
 
 def reduce_scatter(x, mesh, axis_name: str):
@@ -109,10 +108,10 @@ def reduce_scatter(x, mesh, axis_name: str):
         chunk = local.shape[0] // n
         return jax.lax.dynamic_slice_in_dim(y, i * chunk, chunk, axis=0)
 
-    return shard_map(f, mesh=mesh,
-                     in_specs=_shard_spec(x.ndim, axis_name),
-                     out_specs=_shard_spec(x.ndim, axis_name),
-                     check_rep=False)(x)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=_shard_spec(x.ndim, axis_name),
+                         out_specs=_shard_spec(x.ndim, axis_name),
+                         check_vma=False)(x)
 
 
 def ring_gather_stack(local, axis_name: str, n: int):
@@ -146,6 +145,6 @@ def ring_all_gather(x, mesh, axis_name: str):
         out = ring_gather_stack(local, axis_name, n)
         return out.reshape((n * local.shape[0],) + local.shape[1:])
 
-    return shard_map(f, mesh=mesh,
-                     in_specs=_shard_spec(x.ndim, axis_name),
-                     out_specs=P(*([None] * x.ndim)), check_rep=False)(x)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=_shard_spec(x.ndim, axis_name),
+                         out_specs=P(*([None] * x.ndim)), check_vma=False)(x)

@@ -2,9 +2,7 @@
 
 Every kernel takes ``interpret=None`` and resolves it per process via
 ``pallas_env.default_interpret``: native lowering when the default
-backend is a TPU, the Python interpreter elsewhere. Override both ways
-with ``REPRO_PALLAS_COMPILE=1`` (force native) / ``=0`` (force
-interpreter).
+backend is a TPU, the Python interpreter on every other backend.
 """
 from __future__ import annotations
 

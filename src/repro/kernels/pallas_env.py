@@ -1,22 +1,13 @@
 """Pallas execution-mode selection shared by every kernel in this package.
 
-``interpret=None`` (the kernels' default) resolves per process:
-
-  - ``REPRO_PALLAS_COMPILE=1``  -> native lowering, ``=0`` -> interpreter
-    (explicit override, both directions);
-  - otherwise native iff the default backend is a real TPU — CPU/GPU
-    containers fall back to the Python interpreter, TPU deployments lower
-    natively instead of silently running the emulator.
+``interpret=None`` (the kernels' default) resolves per process: native
+lowering iff the default backend is a TPU, the Python interpreter on every
+other backend (the CPU test suite).
 """
 from __future__ import annotations
-
-import os
 
 
 def default_interpret() -> bool:
     """True = run kernels under the Pallas interpreter (non-TPU backends)."""
-    env = os.environ.get("REPRO_PALLAS_COMPILE")
-    if env is not None:
-        return env != "1"
     import jax
     return jax.default_backend() != "tpu"

@@ -37,15 +37,6 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def cost_stats(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions — older
-    jaxlibs return ``[dict]`` (one per computation), newer return a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def collective_bytes(hlo_text: str) -> dict:
     """Sum result-shape bytes of every collective op in partitioned HLO."""
     out = {k: {"count": 0, "bytes": 0} for k in COLLECTIVES}
@@ -159,7 +150,7 @@ def run_cell(arch_id: str, shape: str, mesh, mesh_name: str,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = cost_stats(compiled)
+            cost = compiled.cost_analysis()
             coll = collective_bytes(compiled.as_text())
             # Loop-aware cost extrapolation: compile depth-1 and depth-2
             # variants; per-layer cost = f(2) - f(1); total = f(1)+(L-1)*per.
@@ -172,7 +163,7 @@ def run_cell(arch_id: str, shape: str, mesh, mesh_name: str,
                     j2, a2 = lower_cell(arch_id, shape, mesh, depth=dd,
                                         variant=variant)
                     c2 = j2.lower(*a2).compile()
-                    cost2 = cost_stats(c2)
+                    cost2 = c2.cost_analysis()
                     probes.append({
                         "flops": float(cost2.get("flops", 0.0)),
                         "bytes": float(cost2.get("bytes accessed", 0.0)),

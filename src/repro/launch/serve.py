@@ -10,9 +10,9 @@ micro-batches (``--k-mix`` draws per-request depths), query-length
 routing (``--routing table8``; ``--engine``/``--shards`` configure the
 single-route policy otherwise), and an LRU response cache (``--cache N``
 entries; the workload repeats queries, so hits show up immediately in
-the printed stats). ``--shards N`` uses a one-axis mesh when N devices
-exist (``--host-devices`` fakes them on CPU), else the single-device
-vmap emulation path (bit-identical results).
+the printed stats). ``--shards N`` serves over a one-axis mesh of N
+devices and refuses to start with fewer (``--host-devices`` fakes them
+on CPU). The process exits non-zero when any request failed.
 
 Observability: ``--metrics-port N`` serves the live registry over HTTP
 (``/metrics`` Prometheus text, ``/metrics.json``, ``/traces``; port 0
@@ -60,6 +60,7 @@ def main() -> None:
 
     from repro.core import build_index, twolevel
     from repro.data import make_corpus
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.retrieval import SearchRequest, engine_names
     from repro.serve import (AsyncRetrievalScheduler, RetryPolicy,
                              SchedulerConfig, make_shard_mesh,
@@ -135,6 +136,11 @@ def main() -> None:
                          "scripts/fit_cost_model.py) and sort batches "
                          "by predicted chunk count")
     args = ap.parse_args()
+    if args.shards > len(jax.devices()):
+        ap.error(f"--shards {args.shards} needs {args.shards} devices, "
+                 f"have {len(jax.devices())} "
+                 f"({jax.devices()[0].platform})")
+    enable_compile_cache()
     corpus = make_corpus(args.preset, n_docs=args.docs, n_terms=4096,
                          n_queries=64)
     index = build_index(corpus.merged("scaled"), tile_size=1024)
@@ -145,12 +151,10 @@ def main() -> None:
             ap.error("--shards/--engine sharded cannot combine with "
                      "--routing (the sharded engine is a single route); "
                      "drop one of the flags")
-        mesh = (make_shard_mesh(args.shards)
-                if 1 < args.shards <= len(jax.devices()) else None)
-        routing = single_route("sharded", n_shards=args.shards, mesh=mesh,
+        routing = single_route("sharded", n_shards=args.shards,
+                               mesh=make_shard_mesh(args.shards),
                                exchange_every=args.exchange_every)
-        path = "mesh" if mesh is not None else "emulated"
-        print(f"# sharded serving: {args.shards} shards ({path})")
+        print(f"# sharded serving: {args.shards} shards (mesh)")
     elif args.routing == "table8":
         # --engine still matters under routing: it serves the long class
         routing = table8_policy(long_engine=args.engine)
@@ -231,6 +235,9 @@ def main() -> None:
                       f"{span['attrs']}")
     if server is not None:
         server.close()
+    if stats["failed"]:
+        sys.exit(f"error: {stats['failed']} of {stats['submitted']} "
+                 f"requests failed")
 
 
 def cli() -> None:

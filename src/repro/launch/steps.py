@@ -176,7 +176,6 @@ def make_serve_step(arch: ArchSpec, shape: str, cfg, rules: T.Rules,
                 return u @ v.T
             return tt_serve
         if sharded_topk and mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             axes = tuple(mesh.axis_names)
             import numpy as _np
@@ -195,9 +194,10 @@ def make_serve_step(arch: ArchSpec, shape: str, cfg, rules: T.Rules,
                         flat = flat * mesh.shape[a] + jax.lax.axis_index(a)
                     return v, i + flat * local_n
 
-                v, i = shard_map(local, mesh=mesh,
-                                 in_specs=(P(axes, None), P()),
-                                 out_specs=(P(axes), P(axes)))(cand_emb, u)
+                v, i = jax.shard_map(local, mesh=mesh,
+                                     in_specs=(P(axes, None), P()),
+                                     out_specs=(P(axes), P(axes)),
+                                     check_vma=False)(cand_emb, u)
                 tv, ti = jax.lax.top_k(v, TOPK_SERVE)
                 return tv, i[ti]
             return tt_retr_sharded

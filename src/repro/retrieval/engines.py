@@ -177,7 +177,8 @@ class ShardedEngine:
     """Mesh-sharded tile ranges with a collective top-k merge.
 
     Accepts a ``BlockedImpactIndex`` (partitioned here via ``n_shards``)
-    or a prebuilt ``core.shard_plan.ShardedImpactIndex``. ``mesh=None``
+    or a prebuilt ``core.shard_plan.ShardedImpactIndex``. With a mesh, the
+    stacked shard leaves are placed on it here, once; ``mesh=None``
     serves through the single-device vmap emulation path.
     """
 
@@ -188,7 +189,8 @@ class ShardedEngine:
                  chunk_tiles: int | None = None):
         # deferred: serve.sharded imports serve.engine, which uses the
         # Retriever facade — a module-level import here would be circular
-        from ..core.shard_plan import ShardedImpactIndex, shard_index
+        from ..core.shard_plan import (ShardedImpactIndex, place_on_mesh,
+                                       shard_index)
         if traversal not in ("full", "chunked"):
             raise ValueError(f"engine {self.name!r} supports traversal in "
                              f"('full', 'chunked'), got {traversal!r}")
@@ -199,6 +201,10 @@ class ShardedEngine:
         else:
             self.sharded = shard_index(_require_bii(index, self.name),
                                        n_shards or 1)
+        if mesh is not None:
+            # a replica hands over an already placed index: device_put to
+            # the sharding it already has moves nothing
+            self.sharded = place_on_mesh(self.sharded, mesh, axis_name)
         self.mesh = mesh
         self.axis_name = axis_name
         self.use_kernel = use_kernel

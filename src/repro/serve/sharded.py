@@ -38,7 +38,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.plan import chunk_schedule, plan_query, tile_schedule
@@ -384,13 +383,13 @@ def _sharded_impl_mesh(gather, tm_b, tm_l, doc_base,
     scal = P()
     # per-leaf shard specs: every gather leaf is stacked on the shard axis
     gspec = tuple(P(axis_name, *([None] * (a.ndim - 1))) for a in gather)
-    f = shard_map(
+    f = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(gspec, sh3, sh3, sh, sh,
                   rep1, rep1, rep2, rep2, rep2,
                   scal, scal, scal, scal),
         out_specs=(rep2, rep2, rep2, rep2, rep2, rep2, sh3, P(axis_name, None)),
-        check_rep=False)
+        check_vma=False)
     out = f(gather, tm_b, tm_l, doc_base, n_real,
             sigma_b, sigma_l, q_terms, qw_b, qw_l,
             alpha, beta, gamma, factor)

@@ -13,7 +13,7 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax
     from repro.launch.mesh import make_mesh, dp_axes
-    from repro.launch.dryrun import collective_bytes, cost_stats, lower_cell
+    from repro.launch.dryrun import collective_bytes, lower_cell
 
     assert jax.device_count() == 8  # dryrun's setdefault kept our count
     mesh = make_mesh(dp=4, tp=2)
@@ -26,7 +26,7 @@ SCRIPT = textwrap.dedent("""
         with mesh:
             jitted, args = lower_cell(arch, shape, mesh)
             compiled = jitted.lower(*args).compile()
-            cost = cost_stats(compiled)
+            cost = compiled.cost_analysis()
             coll = collective_bytes(compiled.as_text())
             out[f"{arch}/{shape}"] = {
                 "flops": float(cost.get("flops", -1)),

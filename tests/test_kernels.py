@@ -135,6 +135,23 @@ def test_guided_score_chunk_all_skipped_is_zero():
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
 
+def test_guided_score_refuses_runs_wider_than_max_pad_len():
+    """A tile wider than the kernels fit in VMEM is refused by name, not
+    left to a compiler out-of-memory error on the chip."""
+    from repro.kernels.guided_score import MAX_PAD_LEN
+    p = 2 * MAX_PAD_LEN
+    rows = jax.ShapeDtypeStruct((1, 2, p), jnp.float32)
+    per_tile = jax.ShapeDtypeStruct((1, 2), jnp.float32)
+    s = jnp.float32(0.0)
+    with pytest.raises(ValueError, match="pad_len <= 4096"):
+        jax.eval_shape(
+            lambda o, b, l, e, pb: guided_score_chunk(
+                o, b, l, e, pb, jnp.zeros(1, jnp.int32), s, s, s, s,
+                tile_size=p),
+            jax.ShapeDtypeStruct((1, 2, p), jnp.int32), rows, rows,
+            per_tile, per_tile)
+
+
 @pytest.mark.parametrize("h,hkv,sq,skv,d,causal,off", [
     (4, 4, 128, 128, 64, True, 0),
     (8, 2, 128, 256, 64, True, 128),   # GQA + decode-style offset

@@ -191,6 +191,56 @@ def test_one_device_mesh_equals_emulation(setup):
     np.testing.assert_array_equal(msh.scores, emu.scores)
 
 
+def test_engine_places_shards_on_mesh(setup):
+    """Opening the sharded engine on a mesh lays every stacked leaf out
+    over the shard axis once (NamedSharding), and serving from the placed
+    index equals the emulation path."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.retrieval import Retriever
+    corpus, index = setup
+    p = twolevel.fast()
+    retr = Retriever.open(index, p, "sharded", mesh=make_shard_mesh(1))
+    sh = retr.engine.sharded
+    for leaf in (*sh.gather, sh.tile_max_b, sh.doc_base):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.spec == P("shard", *([None] * (leaf.ndim - 1)))
+    assert sh.sigma_b.sharding.spec == P()
+    assert retr.replicate().engine.sharded.gather[0] is sh.gather[0]
+    emu = shard_retrieve_batched(shard_index(index, 1), *_q(corpus), p)
+    got = retr.search(terms=corpus.queries, weights_b=corpus.q_weights_b,
+                      weights_l=corpus.q_weights_l, k=K)
+    np.testing.assert_array_equal(got.ids, emu.ids)
+
+
+def test_launch_serve_refuses_more_shards_than_devices(monkeypatch, capsys):
+    """``--shards N`` needs N devices: no silent single-device fallback."""
+    from repro.launch import serve
+    monkeypatch.setattr(sys, "argv", ["serve", "--shards", "4"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code == 2
+    assert "needs 4 devices" in capsys.readouterr().err
+
+
+def test_launch_serve_exits_nonzero_when_requests_fail(monkeypatch):
+    """A run whose batches all fail must not exit 0."""
+    from repro.launch import compile_cache, serve
+    from repro.retrieval import Retriever
+
+    def broken(self, *a, **kw):
+        raise RuntimeError("engine down")
+    monkeypatch.setattr(Retriever, "search", broken)
+    # the test process keeps its compile cache off
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", ["serve", "--docs", "2048",
+                                      "--requests", "4", "--qps", "1000"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert "4 of 4 requests failed" in str(exc.value.code)
+
+
 # -- chunked per-shard traversal ----------------------------------------------
 
 @pytest.mark.parametrize("exchange_every", [0, 2])
@@ -296,7 +346,7 @@ _MESH_PARITY_SCRIPT = textwrap.dedent("""
     import json
     import numpy as np
     from repro.core import build_index, twolevel
-    from repro.core.shard_plan import shard_index
+    from repro.core.shard_plan import place_on_mesh, shard_index
     from repro.core.traversal import retrieve_batched
     from repro.data import make_corpus
     from repro.serve.sharded import make_shard_mesh, shard_retrieve_batched
@@ -338,6 +388,14 @@ _MESH_PARITY_SCRIPT = textwrap.dedent("""
         shard_retrieve_batched(sh, *q, pc, mesh=mesh, traversal="chunked"),
         shard_retrieve_batched(sh, *q, pc.replace(schedule="impact"),
                                mesh=mesh))
+    # placed once at open: device s holds shard s of every stacked leaf
+    placed = place_on_mesh(sh, mesh)
+    owners = [{d.id for d in leaf.sharding.device_set}
+              for leaf in placed.gather]
+    rows = [s.data.shape[0] for s in placed.gather[0].addressable_shards]
+    out["placed"] = (all(len(o) == 8 for o in owners) and rows == [1] * 8
+                     and eq(shard_retrieve_batched(placed, *q, p, mesh=mesh),
+                            ref))
     print("RESULT:" + json.dumps(out))
 """)
 

@@ -1,0 +1,117 @@
+"""TPU v5e compiles of the guided_score kernels at the chip smoke's widths.
+
+Interpret mode (every other kernel test) cannot see what the TPU compiler
+refuses: Mosaic relayouts, block tiling rules, scoped VMEM. These tests lower
+each kernel for a described, unattached v5e and assert that the compiled
+program holds the native kernel (``tpu_custom_call``). The chunk kernels are
+also compiled under ``vmap``, the way the batched traversal calls them.
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library at a time, so every xdist worker must
+collect the same tests and only the worker that runs this file loads it.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.index.compressed import raw_words_len
+from repro.kernels.guided_score import (MAX_PAD_LEN, guided_score_chunk,
+                                        guided_score_chunk_q,
+                                        guided_score_tile,
+                                        guided_score_tile_q)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it out entirely."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _args(name, cfg, sds, batch=()):
+    """Abstract inputs of one kernel at ``cfg``'s widths (pad_len reaches
+    tile_size: the head terms of a 2^20-doc corpus fill tiles)."""
+    f32, i32 = jnp.float32, jnp.int32
+    nq, p, c = cfg.query_terms, cfg.tile_size, cfg.chunk_tiles
+    wp = raw_words_len(p)
+    b = tuple(batch)
+    s = lambda *shape: b + shape
+    scal = tuple(sds(s(), f32) for _ in range(4))
+    if name == "tile":
+        return (sds(s(nq, p), i32), sds(s(nq, p), f32), sds(s(nq, p), f32),
+                sds(s(nq), f32), sds(s(nq), f32), *scal)
+    if name == "chunk":
+        return (sds(s(c, nq, p), i32), sds(s(c, nq, p), f32),
+                sds(s(c, nq, p), f32), sds(s(c, nq), f32),
+                sds(s(c, nq), f32), sds(s(c), i32), *scal)
+    if name == "tile_q":
+        return (sds(s(nq, wp), i32), sds(s(nq, p), f32), sds(s(nq, p), f32),
+                sds(s(3, nq), i32), sds(s(4, nq), f32), sds(s(nq), f32),
+                sds(s(nq), f32), sds(s(nq), f32), sds(s(nq), f32), *scal)
+    return (sds(s(c, nq, wp), i32), sds(s(c, nq, p), f32),
+            sds(s(c, nq, p), f32), sds(s(c, 3, nq), i32),
+            sds(s(c, 4, nq), f32), sds(s(nq), f32), sds(s(nq), f32),
+            sds(s(c, nq), f32), sds(s(c, nq), f32), sds(s(c), i32), *scal)
+
+
+_KERNELS = {"tile": guided_score_tile, "chunk": guided_score_chunk,
+            "tile_q": guided_score_tile_q, "chunk_q": guided_score_chunk_q}
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "vmap"])
+@pytest.mark.parametrize("name", list(_KERNELS))
+def test_guided_score_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       chip_smoke, name, batched):
+    cfg = chip_smoke.Config()
+    kw = dict(tile_size=cfg.tile_size, interpret=False)
+    if name.endswith("_q"):
+        kw["pad_len"] = cfg.tile_size
+    fn = functools.partial(_KERNELS[name], **kw)
+    if batched:
+        fn = jax.vmap(fn)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = _args(name, cfg, sds, batch=(cfg.max_batch,) if batched else ())
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_q"])
+def test_chunk_kernels_compile_at_max_pad_len(one_chip, no_persistent_cache,
+                                              chip_smoke, name):
+    """The widest run the kernels accept still fits VMEM on a v5e."""
+    import dataclasses
+    cfg = dataclasses.replace(chip_smoke.Config(), tile_size=MAX_PAD_LEN)
+    kw = dict(tile_size=MAX_PAD_LEN, interpret=False)
+    if name == "chunk_q":
+        kw["pad_len"] = MAX_PAD_LEN
+    fn = jax.vmap(functools.partial(_KERNELS[name], **kw))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = _args(name, cfg, sds, batch=(cfg.max_batch,))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
