@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import scope
 from .index import BlockedImpactIndex, dispatch_gather, gather_tile
 from .plan import (QueryPlan, chunk_schedule, combine, essential_terms,
                    freeze_bounds, plan_query, term_bounds, tile_schedule,
@@ -168,16 +169,17 @@ def _score_tile_kernel(offs, wb, wl, essential, prefix_beta, th_lo,
     # The kernel reports only the post-partition masks; presence is
     # re-derived from the gathered offsets exactly as score_tile counts it
     # (one scatter over doc slots), so both paths report identical stats.
-    valid = offs >= 0
-    S = tile_size
-    offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
-    cnt = jax.ops.segment_sum(valid.ravel().astype(jnp.float32),
-                              offs_safe.ravel(), num_segments=S + 1)[:S]
-    present = cnt > 0
-    stats = jnp.stack([present.sum().astype(jnp.float32),
-                       rank_m.sum(),
-                       (rank_mask & ~eval_mask).sum().astype(jnp.float32),
-                       valid.sum().astype(jnp.float32)])
+    with jax.named_scope("stats"):
+        valid = offs >= 0
+        S = tile_size
+        offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
+        cnt = jax.ops.segment_sum(valid.ravel().astype(jnp.float32),
+                                  offs_safe.ravel(), num_segments=S + 1)[:S]
+        present = cnt > 0
+        stats = jnp.stack([present.sum().astype(jnp.float32),
+                           rank_m.sum(),
+                           (rank_mask & ~eval_mask).sum().astype(jnp.float32),
+                           valid.sum().astype(jnp.float32)])
     return (_tile_topk(g, eval_mask, kq), _tile_topk(l, eval_mask, kq),
             _tile_topk(r, rank_mask, kq), stats)
 
@@ -201,22 +203,27 @@ def _score_tile_kernel_q(gt, plan: QueryPlan, tile, essential, prefix_beta,
     decode, so decompression stays inside the memory-bound gather)."""
     from ..index.compressed import gather_tile_q_raw
     from ..kernels.guided_score import guided_score_tile_q
-    words, qb_row, ql_row, meta_i, meta_f = gather_tile_q_raw(
-        gt, plan.qt, tile, pad_len=pad_len)
-    out = guided_score_tile_q(
-        words, qb_row, ql_row, meta_i, meta_f, plan.qwb, plan.qwl,
-        essential.astype(jnp.float32), prefix_beta, th_lo,
-        alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
-        block_s=min(512, tile_size))
-    g, l, r, eval_m, rank_m, slot_cnt = out
-    eval_mask = eval_m > 0
-    rank_mask = rank_m > 0
-    stats = jnp.stack([(slot_cnt > 0).sum().astype(jnp.float32),
-                       rank_m.sum(),
-                       (rank_mask & ~eval_mask).sum().astype(jnp.float32),
-                       slot_cnt.sum()])
-    return (_tile_topk(g, eval_mask, kq), _tile_topk(l, eval_mask, kq),
-            _tile_topk(r, rank_mask, kq), stats)
+    with jax.named_scope("gather"):
+        words, qb_row, ql_row, meta_i, meta_f = gather_tile_q_raw(
+            gt, plan.qt, tile, pad_len=pad_len)
+    with jax.named_scope("score"):
+        out = guided_score_tile_q(
+            words, qb_row, ql_row, meta_i, meta_f, plan.qwb, plan.qwl,
+            essential.astype(jnp.float32), prefix_beta, th_lo,
+            alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
+            block_s=min(512, tile_size))
+        g, l, r, eval_m, rank_m, slot_cnt = out
+        eval_mask = eval_m > 0
+        rank_mask = rank_m > 0
+        candidates = (_tile_topk(g, eval_mask, kq),
+                      _tile_topk(l, eval_mask, kq),
+                      _tile_topk(r, rank_mask, kq))
+    with jax.named_scope("stats"):
+        stats = jnp.stack([(slot_cnt > 0).sum().astype(jnp.float32),
+                           rank_m.sum(),
+                           (rank_mask & ~eval_mask).sum().astype(jnp.float32),
+                           slot_cnt.sum()])
+    return (*candidates, stats)
 
 
 def _tile_step(idx_arrays, plan: QueryPlan, carry, tile,
@@ -249,13 +256,14 @@ def _tile_step(idx_arrays, plan: QueryPlan, carry, tile,
     th_gl = th_gl * factor
     th_lo = lv[-1] * factor
 
-    m_alpha, m_beta, ub_gl = term_bounds(plan, tile_max_b, tile_max_l, tile,
-                                         alpha, beta, bound_mode)
-    skip = ub_gl <= th_gl
-    if tile_valid is not None:
-        skip = skip | ~tile_valid
-    essential = essential_terms(m_alpha, th_gl)
-    prefix_beta = freeze_bounds(m_beta)
+    with jax.named_scope("bounds"):
+        m_alpha, m_beta, ub_gl = term_bounds(plan, tile_max_b, tile_max_l,
+                                             tile, alpha, beta, bound_mode)
+        skip = ub_gl <= th_gl
+        if tile_valid is not None:
+            skip = skip | ~tile_valid
+        essential = essential_terms(m_alpha, th_gl)
+        prefix_beta = freeze_bounds(m_beta)
 
     if use_kernel and gather_kind == "q8":
         # compressed + kernel: decode happens inside the pallas_call
@@ -263,13 +271,16 @@ def _tile_step(idx_arrays, plan: QueryPlan, carry, tile,
             gt, plan, tile, essential, prefix_beta, th_lo,
             alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len, kq=kq)
     else:
-        offs, wb, wl = dispatch_gather(gather_kind, gt, plan.qt, tile,
-                                       plan.qwb, plan.qwl,
-                                       pad_len=pad_len, tile_size=tile_size)
+        with jax.named_scope("gather"):
+            offs, wb, wl = dispatch_gather(gather_kind, gt, plan.qt, tile,
+                                           plan.qwb, plan.qwl,
+                                           pad_len=pad_len,
+                                           tile_size=tile_size)
         scorer = _score_tile_kernel if use_kernel else score_tile
-        g_c, l_c, r_c, stats = scorer(
-            offs, wb, wl, essential, prefix_beta, th_lo, alpha, beta, gamma,
-            tile_size=tile_size, kq=kq)
+        with jax.named_scope("score"):
+            g_c, l_c, r_c, stats = scorer(
+                offs, wb, wl, essential, prefix_beta, th_lo, alpha, beta,
+                gamma, tile_size=tile_size, kq=kq)
 
     base = tile * tile_size
 
@@ -278,11 +289,13 @@ def _tile_step(idx_arrays, plan: QueryPlan, carry, tile,
         vals = jnp.where(skip, NEG_INF, vals)
         return vals, base + idx
 
-    gv, gi = _merge_queue(gv, gi, *masked(g_c), k)
-    lv, li = _merge_queue(lv, li, *masked(l_c), k)
-    rv, ri = _merge_queue(rv, ri, *masked(r_c), k)
-    visited = jnp.where(skip, 0.0, 1.0)
-    st = st + jnp.concatenate([jnp.where(skip, 0.0, stats), visited[None]])
+    with jax.named_scope("merge"):
+        gv, gi = _merge_queue(gv, gi, *masked(g_c), k)
+        lv, li = _merge_queue(lv, li, *masked(l_c), k)
+        rv, ri = _merge_queue(rv, ri, *masked(r_c), k)
+        visited = jnp.where(skip, 0.0, 1.0)
+        st = st + jnp.concatenate([jnp.where(skip, 0.0, stats),
+                                   visited[None]])
     return (gv, gi, lv, li, rv, ri, st)
 
 
@@ -367,58 +380,67 @@ def _chunk_step_fused(idx_arrays, plan, carry, tiles_chunk,
     th_gl = th_gl * factor
     th_lo = lv[-1] * factor
 
-    m_alpha, m_beta, ub_gl = jax.vmap(
-        lambda t: term_bounds(plan, tile_max_b, tile_max_l, t,
-                              alpha, beta, bound_mode))(tiles_chunk)
-    skip = (ub_gl <= th_gl) | (tiles_chunk >= n_valid)        # [C]
-    essential = jax.vmap(essential_terms, in_axes=(0, None))(m_alpha, th_gl)
-    prefix_beta = jax.vmap(freeze_bounds)(m_beta)
+    with jax.named_scope("bounds"):
+        m_alpha, m_beta, ub_gl = jax.vmap(
+            lambda t: term_bounds(plan, tile_max_b, tile_max_l, t,
+                                  alpha, beta, bound_mode))(tiles_chunk)
+        skip = (ub_gl <= th_gl) | (tiles_chunk >= n_valid)    # [C]
+        essential = jax.vmap(essential_terms, in_axes=(0, None))(m_alpha,
+                                                                 th_gl)
+        prefix_beta = jax.vmap(freeze_bounds)(m_beta)
 
     if gather_kind == "q8":
         from ..index.compressed import gather_tile_q_raw
-        words, qbr, qlr, meta_i, meta_f = jax.vmap(
-            lambda t: gather_tile_q_raw(gt, plan.qt, t, pad_len=pad_len)
-        )(tiles_chunk)
-        out = guided_score_chunk_q(
-            words, qbr, qlr, meta_i, meta_f, plan.qwb, plan.qwl,
-            essential.astype(jnp.float32), prefix_beta, skip, th_lo,
-            alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
-            block_s=min(512, tile_size))
-        # posting presence/counts come from the kernel's 6th output row
-        slot_cnt = out[:, 5]                                  # [C, S]
-        present = (slot_cnt > 0).sum(1).astype(jnp.float32)
-        postings = slot_cnt.sum(1)
+        with jax.named_scope("gather"):
+            words, qbr, qlr, meta_i, meta_f = jax.vmap(
+                lambda t: gather_tile_q_raw(gt, plan.qt, t, pad_len=pad_len)
+            )(tiles_chunk)
+        with jax.named_scope("score"):
+            out = guided_score_chunk_q(
+                words, qbr, qlr, meta_i, meta_f, plan.qwb, plan.qwl,
+                essential.astype(jnp.float32), prefix_beta, skip, th_lo,
+                alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
+                block_s=min(512, tile_size))
+        with jax.named_scope("stats"):
+            # posting presence/counts come from the kernel's 6th output row
+            slot_cnt = out[:, 5]                              # [C, S]
+            present = (slot_cnt > 0).sum(1).astype(jnp.float32)
+            postings = slot_cnt.sum(1)
     else:
         docids, w_b, w_l, tile_ptr = gt
-        offs, wb, wl = jax.vmap(
-            lambda t: _gather_tile(docids, w_b, w_l, tile_ptr,
-                                   plan.qt, plan.qwb, plan.qwl, t,
-                                   pad_len=pad_len, tile_size=tile_size)
-        )(tiles_chunk)                                        # [C, Nq, P]
-        out = guided_score_chunk(offs, wb, wl, essential.astype(jnp.float32),
-                                 prefix_beta, skip, th_lo, alpha, beta, gamma,
-                                 tile_size=tile_size,
-                                 block_s=min(512, tile_size))
+        with jax.named_scope("gather"):
+            offs, wb, wl = jax.vmap(
+                lambda t: _gather_tile(docids, w_b, w_l, tile_ptr,
+                                       plan.qt, plan.qwb, plan.qwl, t,
+                                       pad_len=pad_len, tile_size=tile_size)
+            )(tiles_chunk)                                    # [C, Nq, P]
+        with jax.named_scope("score"):
+            out = guided_score_chunk(
+                offs, wb, wl, essential.astype(jnp.float32), prefix_beta,
+                skip, th_lo, alpha, beta, gamma, tile_size=tile_size,
+                block_s=min(512, tile_size))
         # Stats exactly as _score_tile_kernel derives them, chunk-vectorized:
         # presence re-counted from the gathered offsets (one scatter/tile).
-        S = tile_size
-        valid = offs >= 0
-        offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
+        with jax.named_scope("stats"):
+            S = tile_size
+            valid = offs >= 0
+            offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
 
-        def present_one(v, o):
-            cnt = jax.ops.segment_sum(v.ravel().astype(jnp.float32),
-                                      o.ravel(), num_segments=S + 1)[:S]
-            return (cnt > 0).sum().astype(jnp.float32)
-        present = jax.vmap(present_one)(valid, offs_safe)
-        postings = valid.sum((1, 2)).astype(jnp.float32)
+            def present_one(v, o):
+                cnt = jax.ops.segment_sum(v.ravel().astype(jnp.float32),
+                                          o.ravel(), num_segments=S + 1)[:S]
+                return (cnt > 0).sum().astype(jnp.float32)
+            present = jax.vmap(present_one)(valid, offs_safe)
+            postings = valid.sum((1, 2)).astype(jnp.float32)
 
     g, l, r = out[:, 0], out[:, 1], out[:, 2]
     eval_mask = out[:, 3] > 0
     rank_mask = out[:, 4] > 0
-    tile_stats = jnp.stack(
-        [present, out[:, 4].sum(1),
-         (rank_mask & ~eval_mask).sum(1).astype(jnp.float32),
-         postings], axis=1)                                   # [C, 4]
+    with jax.named_scope("stats"):
+        tile_stats = jnp.stack(
+            [present, out[:, 4].sum(1),
+             (rank_mask & ~eval_mask).sum(1).astype(jnp.float32),
+             postings], axis=1)                               # [C, 4]
 
     def merge_step(c, xs):
         gv, gi, lv, li, rv, ri, st = c
@@ -435,9 +457,10 @@ def _chunk_step_fused(idx_arrays, plan, carry, tiles_chunk,
         st = st + jnp.concatenate([jnp.where(sk_t, 0.0, st_t),
                                    visited[None]])
         return (gv, gi, lv, li, rv, ri, st), None
-    carry, _ = jax.lax.scan(
-        merge_step, carry,
-        (tiles_chunk, g, l, r, eval_mask, rank_mask, skip, tile_stats))
+    with jax.named_scope("merge"):
+        carry, _ = jax.lax.scan(
+            merge_step, carry,
+            (tiles_chunk, g, l, r, eval_mask, rank_mask, skip, tile_stats))
     return carry
 
 
@@ -564,41 +587,48 @@ def retrieve_batched(index: BlockedImpactIndex, q_terms, qw_b, qw_l,
     if traversal not in TRAVERSALS:
         raise ValueError(f"traversal must be in {TRAVERSALS}, "
                          f"got {traversal!r}")
-    q_terms = jnp.asarray(q_terms, dtype=jnp.int32)
-    qw_b = jnp.asarray(qw_b, dtype=jnp.float32)
-    qw_l = jnp.asarray(qw_l, dtype=jnp.float32)
     k = resolve_k(params, k)
     kq = min(k, index.tile_size)
-    arrays = (index.gather_arrays(),
-              index.tile_max_b, index.tile_max_l,
-              index.sigma_b, index.sigma_l, q_terms, qw_b, qw_l,
-              jnp.float32(params.alpha), jnp.float32(params.beta),
-              jnp.float32(params.gamma), jnp.float32(params.threshold_factor))
-    statics = dict(k=k, kq=kq, pad_len=index.pad_len,
-                   tile_size=index.tile_size, bound_mode=params.bound_mode,
-                   gather_kind=index.gather_kind)
-    disp = None
-    if traversal == "full":
-        out = _retrieve_batched_impl(*arrays, n_tiles=index.n_tiles,
-                                     schedule=params.schedule,
-                                     use_kernel=use_kernel, **statics)
-    else:
-        ct = int(chunk_tiles if chunk_tiles is not None
-                 else params.chunk_tiles)
-        out, disp = _retrieve_chunked_impl(
-            *arrays, n_tiles=index.n_tiles, chunk_tiles=ct,
-            use_kernel=use_kernel, fused=traversal == "chunked_fused",
-            **statics)
-    gv, gi, lv, li, rv, ri, st = jax.tree_util.tree_map(np.asarray, out)
-    stats = dict(zip(STAT_KEYS, st.T))
-    b = q_terms.shape[0]
-    stats["n_tiles"] = np.full(b, index.n_tiles, np.float32)
-    if disp is not None:
-        stats["chunks_dispatched"] = np.asarray(disp)
-        stats["n_chunks"] = np.full(b, -(-index.n_tiles // ct), np.float32)
-    return RetrievalResult(ids=index.to_orig(ri), scores=rv,
-                           global_ids=index.to_orig(gi),
-                           local_ids=index.to_orig(li), stats=stats)
+    with scope("dispatch"):        # host-to-device and enqueue
+        q_terms = jnp.asarray(q_terms, dtype=jnp.int32)
+        qw_b = jnp.asarray(qw_b, dtype=jnp.float32)
+        qw_l = jnp.asarray(qw_l, dtype=jnp.float32)
+        arrays = (index.gather_arrays(),
+                  index.tile_max_b, index.tile_max_l,
+                  index.sigma_b, index.sigma_l, q_terms, qw_b, qw_l,
+                  jnp.float32(params.alpha), jnp.float32(params.beta),
+                  jnp.float32(params.gamma),
+                  jnp.float32(params.threshold_factor))
+        statics = dict(k=k, kq=kq, pad_len=index.pad_len,
+                       tile_size=index.tile_size,
+                       bound_mode=params.bound_mode,
+                       gather_kind=index.gather_kind)
+        disp = None
+        if traversal == "full":
+            out = _retrieve_batched_impl(*arrays, n_tiles=index.n_tiles,
+                                         schedule=params.schedule,
+                                         use_kernel=use_kernel, **statics)
+        else:
+            ct = int(chunk_tiles if chunk_tiles is not None
+                     else params.chunk_tiles)
+            out, disp = _retrieve_chunked_impl(
+                *arrays, n_tiles=index.n_tiles, chunk_tiles=ct,
+                use_kernel=use_kernel, fused=traversal == "chunked_fused",
+                **statics)
+    with scope("device_wait"):
+        out, disp = jax.tree_util.tree_map(np.asarray, (out, disp))
+    with scope("finish"):
+        gv, gi, lv, li, rv, ri, st = out
+        stats = dict(zip(STAT_KEYS, st.T))
+        b = q_terms.shape[0]
+        stats["n_tiles"] = np.full(b, index.n_tiles, np.float32)
+        if disp is not None:
+            stats["chunks_dispatched"] = disp
+            stats["n_chunks"] = np.full(b, -(-index.n_tiles // ct),
+                                        np.float32)
+        return RetrievalResult(ids=index.to_orig(ri), scores=rv,
+                               global_ids=index.to_orig(gi),
+                               local_ids=index.to_orig(li), stats=stats)
 
 
 # ---------------------------------------------------------------------------

@@ -197,6 +197,7 @@ def guided_score_chunk(offs, wb, wl, essential, prefix_beta, skip, th_lo,
                         pltpu.VMEM((nq, block_s), jnp.float32)],
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
+        name="guided_score_chunk",
     )(scal, essential.astype(jnp.float32), prefix_beta.astype(jnp.float32),
       skip.astype(jnp.int32)[None], offs, wb, wl)
 
@@ -345,6 +346,7 @@ def guided_score_chunk_q(words, qb_row, ql_row, meta_i, meta_f, qw_b, qw_l,
                         pltpu.VMEM((nq, pad_len), jnp.float32)],
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
+        name="guided_score_chunk_q",
     )(scal, essential.astype(jnp.float32), prefix_beta.astype(jnp.float32),
       skip.astype(jnp.int32)[None], meta_i.astype(jnp.int32),
       meta_f.astype(jnp.float32), qw, words, qb_row, ql_row)

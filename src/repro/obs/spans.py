@@ -28,15 +28,114 @@ The disabled path is :data:`NULL_TRACER`, a module-level
 allocates. Callers guard attribute assembly with
 ``if tracer.enabled:`` so a disabled pipeline pays a single attribute
 load per request — the overhead-guard test pins this.
+
+Profiler timeline: :func:`scope` is the program's one door to the JAX
+profiler. It opens ``jax.profiler.TraceAnnotation("repro." + name)``,
+so while a profile is collecting, the span sits on the same clock as
+the device's operations; when none is, it costs about a microsecond.
+:meth:`Tracer.span` (and :meth:`NullTracer.span`) open a scope around
+their block; the retroactive :meth:`Tracer.emit` spans stay ring-only.
+:func:`scope_totals` sums, per name, the seconds one thread spends in
+scopes (the executor's ``batch_host_ms``), and :data:`GC_SPANS` puts
+every collection of generation 1 or 2 on the timeline as a ``repro.gc``
+span. jax is imported lazily, so importing ``repro.obs`` never starts a
+backend.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import math
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+
+_THREAD = threading.local()      # .totals: the dict scope_totals fills
+
+
+class _Scope:
+    __slots__ = ("name", "_ann", "_t0", "_totals")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "_Scope":
+        from jax.profiler import TraceAnnotation
+        self._totals = getattr(_THREAD, "totals", None)
+        self._ann = TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._totals is not None:
+            self._totals[self.name] = (self._totals.get(self.name, 0.0)
+                                       + time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
+def scope(name: str) -> _Scope:
+    """A ``repro.<name>`` span on the JAX profiler's timeline around a
+    ``with`` block (nothing is recorded unless a profile is collecting,
+    or :func:`scope_totals` is summing this thread's scopes)."""
+    return _Scope(name)
+
+
+@contextmanager
+def scope_totals(totals: dict | None = None):
+    """Sum into ``totals`` (a fresh dict by default), per scope name, the
+    seconds this thread spends inside :func:`scope` blocks while the
+    ``with`` block runs; yields the dict. Nested blocks each sum their
+    own and the outer one resumes after."""
+    totals = {} if totals is None else totals
+    outer = getattr(_THREAD, "totals", None)
+    _THREAD.totals = totals
+    try:
+        yield totals
+    finally:
+        _THREAD.totals = outer
+
+
+class _GcSpans:
+    """A ``gc.callbacks`` hook that puts each collection of generation 1
+    or 2 on the profiler timeline as a ``repro.gc`` span. Installs are
+    counted: the hook is in ``gc.callbacks`` once while any stands, and
+    the last ``remove`` takes it out."""
+
+    def __init__(self):
+        self.installs = 0
+        self._lock = threading.Lock()
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start" and info["generation"] >= 1:
+            # bound at install: no import may run inside a collection
+            self._open = self._annotation("repro.gc")
+            self._open.__enter__()
+        elif phase == "stop" and self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def install(self) -> None:
+        with self._lock:
+            if self.installs == 0:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation
+                gc.callbacks.append(self)
+            self.installs += 1
+
+    def remove(self) -> None:
+        with self._lock:
+            if self.installs == 0:
+                return
+            self.installs -= 1
+            if self.installs == 0:
+                gc.callbacks.remove(self)
+
+
+# the process's one hook: the scheduler's start() installs, close() removes
+GC_SPANS = _GcSpans()
 
 
 class Span:
@@ -158,7 +257,8 @@ class Tracer:
              **attrs):
         s = self.start(name, trace_id=trace_id, parent=parent, **attrs)
         try:
-            yield s
+            with scope(name):
+                yield s
         finally:
             self.finish(s)
 
@@ -217,7 +317,8 @@ class NullTracer:
 
     @contextmanager
     def span(self, name: str, **kwargs):
-        yield NULL_SPAN
+        with scope(name):
+            yield NULL_SPAN
 
     def export(self, trace_id=None) -> list:
         return []

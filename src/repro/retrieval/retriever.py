@@ -26,6 +26,7 @@ import time
 import numpy as np
 
 from ..core.twolevel import TwoLevelParams, resolve_k
+from ..obs.spans import scope
 from .contract import (K_BUCKETS, SearchRequest, SearchResponse, bucket_k,
                        resolve_ks)
 from .engines import get_engine
@@ -77,8 +78,7 @@ class Retriever:
     layers call through (and later scaling work plugs into)."""
 
     def __init__(self, engine, params: TwoLevelParams,
-                 k_buckets=K_BUCKETS, generation: int = 0,
-                 metrics=None):
+                 k_buckets=K_BUCKETS, generation: int = 0):
         self.engine = engine
         self.params = params
         # sorted: bucket_k picks the first bucket >= k in iteration order
@@ -86,31 +86,21 @@ class Retriever:
         # index generation tag: bumped by the serving hot-swap gate and
         # stamped on every response so stale replicas are detectable
         self.generation = generation
-        # optional obs.MetricsRegistry: each search records its wall
-        # latency into a per-engine histogram (search_ms/<engine>)
-        self.metrics = metrics
-        self._hist_search = (
-            None if metrics is None
-            else metrics.histogram(f"search_ms/{self.engine_name}"))
 
     @classmethod
     def open(cls, index, params: TwoLevelParams | None = None,
              engine: str = "batched", *, k_buckets=K_BUCKETS,
-             generation: int = 0, metrics=None,
-             **engine_opts) -> "Retriever":
+             generation: int = 0, **engine_opts) -> "Retriever":
         """Build a retriever: ``index`` + pruning ``params`` + an engine
         name from the registry. ``index`` may be a fp32
         ``BlockedImpactIndex``, a ``repro.index.CompressedImpactIndex``
         (decode-on-gather; every sparse engine serves it transparently),
         or a ``HybridIndex`` wrapping either. ``engine_opts`` go to the
         engine constructor (e.g. ``n_shards=4, exchange_every=8`` for
-        ``"sharded"``, ``warmup=False`` for ``"sequential"``);
-        ``metrics`` an optional ``repro.obs.MetricsRegistry`` that
-        collects per-engine search latency histograms."""
+        ``"sharded"``, ``warmup=False`` for ``"sequential"``)."""
         params = params if params is not None else TwoLevelParams()
         eng = get_engine(engine)(index, params, **engine_opts)
-        return cls(eng, params, k_buckets=k_buckets, generation=generation,
-                   metrics=metrics)
+        return cls(eng, params, k_buckets=k_buckets, generation=generation)
 
     @property
     def engine_name(self) -> str:
@@ -130,8 +120,7 @@ class Retriever:
                 f"cloning (no .replicate); executor pools need it")
         return Retriever(replicate(self.params), self.params,
                          k_buckets=self.k_buckets,
-                         generation=self.generation,
-                         metrics=self.metrics)
+                         generation=self.generation)
 
     def search(self, request: SearchRequest | None = None, *,
                terms=None, weights_b=None, weights_l=None, dense=None,
@@ -169,8 +158,9 @@ class Retriever:
                 threshold_factor=float(request.threshold_factor))
 
         if request.terms is not None:
-            q_terms, qw_b, qw_l = _pad_queries(
-                request.terms, request.weights_b, request.weights_l)
+            with scope("assemble"):
+                q_terms, qw_b, qw_l = _pad_queries(
+                    request.terms, request.weights_b, request.weights_l)
         else:
             q_terms = qw_b = qw_l = None
 
@@ -178,18 +168,17 @@ class Retriever:
         res = self.engine.search(q_terms, qw_b, qw_l, request.dense,
                                  k=k_exec, params=params)
         latency_ms = (time.perf_counter() - t0) * 1e3
-        if self._hist_search is not None:
-            self._hist_search.record(latency_ms)
-        ids = np.asarray(res.ids)[:, :k_req]
-        scores = np.asarray(res.scores)[:, :k_req]
-        if ks is None:
-            ks = np.full(ids.shape[0], k_req, np.int32)
-        elif (ks < k_req).any():
-            # mixed-k batch: mask each row beyond its own requested depth
-            # with the engines' empty-queue sentinels
-            dead = np.arange(k_req)[None, :] >= ks[:, None]
-            ids = np.where(dead, np.int32(-1), ids)
-            scores = np.where(dead, np.float32(-np.inf), scores)
+        with scope("finish"):
+            ids = np.asarray(res.ids)[:, :k_req]
+            scores = np.asarray(res.scores)[:, :k_req]
+            if ks is None:
+                ks = np.full(ids.shape[0], k_req, np.int32)
+            elif (ks < k_req).any():
+                # mixed-k batch: mask each row beyond its own requested
+                # depth with the engines' empty-queue sentinels
+                dead = np.arange(k_req)[None, :] >= ks[:, None]
+                ids = np.where(dead, np.int32(-1), ids)
+                scores = np.where(dead, np.float32(-np.inf), scores)
         return SearchResponse(
             ids=ids, scores=scores,
             engine=self.engine_name, k=k_req, k_exec=k_exec,

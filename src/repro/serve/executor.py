@@ -52,6 +52,12 @@ from __future__ import annotations
 import threading
 import time
 
+from ..obs.spans import scope, scope_totals
+
+# the executor's own host work on a batch (``batch_host_ms``): every
+# span of the batch but the wait on the device
+HOST_SPANS = ("pick", "assemble", "dispatch", "finish", "deliver")
+
 
 class ReplicaMap(dict):
     """One slot's {route_name: Retriever replica} map, tagged with the
@@ -150,7 +156,13 @@ class ExecutorPool:
         straggler batch from another slot or park on the condition
         until the next deadline. A slot whose breaker is open idles
         until its half-open probe is due (drain waives the gate so
-        ``close`` can never hang on a broken breaker)."""
+        ``close`` can never hang on a broken breaker).
+
+        On the profiler timeline a batch reads ``repro.pick``,
+        ``repro.assemble``, ``repro.dispatch``, ``repro.device_wait``,
+        ``repro.finish``, ``repro.deliver`` on this thread, and idling
+        reads ``repro.park``. The batch's time in all but the device
+        wait goes into the scheduler's ``batch_host_ms`` histogram."""
         sched = self.scheduler
         retrievers = self.replicas.setdefault(slot, ReplicaMap())
         while True:
@@ -166,10 +178,11 @@ class ExecutorPool:
                 sched.faults.on_pick(executor_id=slot)
             now = time.perf_counter()
             if not force and not sched.health.allow(slot, now):
-                with sched._cond:
+                with scope("park"), sched._cond:
                     sched._cond.wait(timeout=0.01)
                 continue
-            picked = sched._pick_batch(now, force)
+            with scope_totals() as spent:
+                picked = sched._pick_batch(now, force)
             if picked is None:
                 # idle: volunteer as the hedge executor for straggler
                 # batches whose primary is another slot
@@ -185,7 +198,7 @@ class ExecutorPool:
                         pass
                 if hedged:
                     continue
-                with sched._cond:
+                with scope("park"), sched._cond:
                     if self._stop:
                         if not self._drain or not sched._groups:
                             return
@@ -198,17 +211,13 @@ class ExecutorPool:
                                    time.perf_counter())
                     sched._cond.wait(timeout=max(wait, 1e-3))
                 continue
-            t_exec = time.perf_counter()
             try:
-                sched._execute(*picked, retrievers=retrievers,
-                               executor_id=slot)
+                with scope_totals(spent):
+                    sched._execute(*picked, retrievers=retrievers,
+                                   executor_id=slot)
             except Exception:
                 # the batch's handles were already failed by _execute;
                 # this executor must keep serving everyone else
                 pass
-            finally:
-                # wall time this slot spent executing (success or not) —
-                # the per-executor utilization signal next to the
-                # scheduler's delivery-side batch_service_ms
-                sched.metrics.histogram("executor_service_ms").record(
-                    (time.perf_counter() - t_exec) * 1e3)
+            sched._hist_host.record(
+                sum(spent.get(n, 0.0) for n in HOST_SPANS) * 1e3)

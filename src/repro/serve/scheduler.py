@@ -53,16 +53,27 @@ batches); cache keys carry the generation, so a rebuild can never
 serve stale hits.
 
 Observability (``repro.obs``): the scheduler always owns a
-:class:`~repro.obs.metrics.MetricsRegistry` (queue-wait and batch
-service-time histograms feed the ``queue_wait_ms`` percentiles in
-``stats()``), and — when ``SchedulerConfig.tracer`` carries a real
-:class:`~repro.obs.spans.Tracer` — records one trace per request
-(admission -> queue -> execute spans, with the batch token, executor
-id and the traversal's ``chunks_dispatched`` attached), emitted
-retroactively at delivery so in-flight requests hold timestamps, not
-span objects. With the default no-op tracer the whole path is a single
-attribute check. ``sort_batches_by_cost`` orders each picked group by
-a trace-fitted chunk-count prediction
+:class:`~repro.obs.metrics.MetricsRegistry` (queue-wait, batch
+service-time and executor host-time histograms feed ``queue_wait_ms``,
+``service_ms`` and ``batch_host_ms`` in ``stats()``). The served path
+writes its own spans onto the JAX profiler's timeline
+(:func:`~repro.obs.spans.scope`; free unless a profile is collecting):
+``repro.admit`` (``submit``), ``repro.pick`` (``_pick_batch``, expiry
+included), ``repro.assemble`` (concatenating and padding the batch
+rows), ``repro.dispatch`` / ``repro.device_wait`` / ``repro.finish``
+(the batched traversal's enqueue, its wait on the device, and the
+result's assembly on the host), ``repro.deliver`` (``_deliver``), the
+executor's ``repro.park`` while no batch is due, and ``repro.gc`` for
+each collection of generation 1 or 2 while the scheduler runs. An
+executor's ``batch_host_ms`` is its time in pick, assemble, dispatch,
+finish and deliver for one batch. When ``SchedulerConfig.tracer``
+carries a real :class:`~repro.obs.spans.Tracer`, the scheduler also
+records one trace per request in its ring (admission -> queue ->
+execute spans, with the batch token, executor id and the traversal's
+``chunks_dispatched`` attached), emitted retroactively at delivery so
+in-flight requests hold timestamps, not span objects. With the default
+no-op tracer that is a single attribute check. ``sort_batches_by_cost``
+orders each picked group by a trace-fitted chunk-count prediction
 (:class:`~repro.obs.cost.CostModel`) within an aged-priority level, so
 micro-batches cluster similar-cost requests and the chunked
 while_loop's max-over-batch trip count hugs the mean; per-query
@@ -94,7 +105,7 @@ import numpy as np
 from ..core.twolevel import TwoLevelParams, resolve_k
 from ..obs.cost import CostModel, QueryFeaturizer
 from ..obs.metrics import Histogram, MetricsRegistry, exact_quantile
-from ..obs.spans import NULL_TRACER
+from ..obs.spans import GC_SPANS, NULL_TRACER, scope
 from ..retrieval import (K_BUCKETS, Retriever, SearchRequest,
                          SearchResponse, bucket_k, resolve_ks)
 from .health import HealthConfig, HealthMonitor, RetryPolicy
@@ -373,6 +384,7 @@ class AsyncRetrievalScheduler:
                         else MetricsRegistry())
         self._hist_queue = self.metrics.histogram("queue_wait_ms")
         self._hist_service = self.metrics.histogram("batch_service_ms")
+        self._hist_host = self.metrics.histogram("batch_host_ms")
         # lazily-built query featurizer (needs only index stats arrays);
         # invalidated by swap_index so features track the live index
         self._featurizer: QueryFeaturizer | None = None
@@ -418,6 +430,7 @@ class AsyncRetrievalScheduler:
         self._executor_batches: dict[int, int] = {}
         self._executor_rows: dict[int, int] = {}
         self._warmup_s = 0.0
+        self._gc_spans = False               # holds a GC_SPANS install
 
     # -- admission -----------------------------------------------------------
 
@@ -453,6 +466,12 @@ class AsyncRetrievalScheduler:
                              "Retriever(engine='dense') directly for dense "
                              "requests")
         now = time.perf_counter() if now is None else now
+        with scope("admit"):
+            return self._submit(request, priority, now)
+
+    def _submit(self, request: SearchRequest, priority: int,
+                now: float) -> SearchHandle:
+        """Classify, pad and admit one validated request."""
         rows, qlen = self._normalize_rows(request)
         if not rows:
             raise ValueError("request carries a zero-row query batch")
@@ -795,7 +814,7 @@ class AsyncRetrievalScheduler:
         retry backoff (``not_before`` in the future) are invisible
         unless ``force`` drains them early; already-expired entries are
         shed first and never picked."""
-        with self._lock:
+        with scope("pick"), self._lock:
             self._expire_locked(now)
             due_key = None
             due_deadline = math.inf
@@ -924,23 +943,24 @@ class AsyncRetrievalScheduler:
 
     def _search_batch(self, retr: Retriever, batch: list, tf):
         """Concatenate + pad one batch to the static shape and run it."""
-        terms = np.concatenate([e.terms for e in batch])
-        qw_b = np.concatenate([e.qw_b for e in batch])
-        qw_l = np.concatenate([e.qw_l for e in batch])
-        ks = np.concatenate([e.ks for e in batch])
-        n_real = terms.shape[0]
-        n_pad = 0
-        if self.cfg.pad_batch and n_real < self.cfg.max_batch:
-            # zero-weight no-op rows: static [max_batch, pad_terms] shape
-            # -> one compile per (k-bucket x length-class), any fill level
-            n_pad = self.cfg.max_batch - n_real
-            terms = np.concatenate(
-                [terms, np.zeros((n_pad, terms.shape[1]), np.int32)])
-            qw_b = np.concatenate(
-                [qw_b, np.zeros((n_pad, qw_b.shape[1]), np.float32)])
-            qw_l = np.concatenate(
-                [qw_l, np.zeros((n_pad, qw_l.shape[1]), np.float32)])
-            ks = np.concatenate([ks, np.ones(n_pad, np.int32)])
+        with scope("assemble"):
+            terms = np.concatenate([e.terms for e in batch])
+            qw_b = np.concatenate([e.qw_b for e in batch])
+            qw_l = np.concatenate([e.qw_l for e in batch])
+            ks = np.concatenate([e.ks for e in batch])
+            n_real = terms.shape[0]
+            n_pad = 0
+            if self.cfg.pad_batch and n_real < self.cfg.max_batch:
+                # zero-weight no-op rows: static [max_batch, pad_terms] shape
+                # -> one compile per (k-bucket x length-class), any fill level
+                n_pad = self.cfg.max_batch - n_real
+                terms = np.concatenate(
+                    [terms, np.zeros((n_pad, terms.shape[1]), np.int32)])
+                qw_b = np.concatenate(
+                    [qw_b, np.zeros((n_pad, qw_b.shape[1]), np.float32)])
+                qw_l = np.concatenate(
+                    [qw_l, np.zeros((n_pad, qw_l.shape[1]), np.float32)])
+                ks = np.concatenate([ks, np.ones(n_pad, np.int32)])
         resp = retr.search(terms=terms, weights_b=qw_b, weights_l=qw_l,
                            k=ks, threshold_factor=tf)
         return resp, n_real, n_pad
@@ -953,7 +973,7 @@ class AsyncRetrievalScheduler:
         result is discarded. Completion notifies the condition — blocked
         submitters and deadline waiters wake immediately."""
         row0 = 0
-        with self._cond:
+        with scope("deliver"), self._cond:
             rec = self._inflight.pop(token, None)
             if rec is None:
                 self._counts["hedges_wasted"] += 1
@@ -1314,6 +1334,7 @@ class AsyncRetrievalScheduler:
         # summaries ({"n": 0} before any delivery — never NaN)
         snap["queue_wait_ms"] = self._hist_queue.summary()
         snap["service_ms"] = self._hist_service.summary()
+        snap["batch_host_ms"] = self._hist_host.summary()
         return snap
 
     def cache_clear(self) -> None:
@@ -1336,6 +1357,9 @@ class AsyncRetrievalScheduler:
         if self.is_running():
             return self
         self._stop = False
+        if not self._gc_spans:
+            GC_SPANS.install()
+            self._gc_spans = True
         if self.cfg.executors > 0:
             from .executor import ExecutorPool  # avoid an import cycle
             self._pool = ExecutorPool(self, self.cfg.executors)
@@ -1360,6 +1384,9 @@ class AsyncRetrievalScheduler:
                 self._cond.notify_all()
             self._thread.join()
             self._thread = None
+        if self._gc_spans:
+            GC_SPANS.remove()
+            self._gc_spans = False
         if flush:
             self.flush()
 

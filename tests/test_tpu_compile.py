@@ -115,3 +115,27 @@ def test_chunk_kernels_compile_at_max_pad_len(one_chip, no_persistent_cache,
     args = _args(name, cfg, sds, batch=(cfg.max_batch,))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["chunk", "chunk_q"])
+def test_chunk_kernels_keep_their_names_in_the_compiled_program(
+        one_chip, no_persistent_cache, chip_smoke, name):
+    """The kernel's instruction is named after its ``pallas_call`` name,
+    not after whatever jitted function calls it, so a trace finds
+    ``guided_score_chunk[_q]`` after any refactor of the callers."""
+    import re
+    cfg = chip_smoke.Config()
+    kw = dict(tile_size=cfg.tile_size, interpret=False)
+    if name == "chunk_q":
+        kw["pad_len"] = cfg.tile_size
+
+    def some_caller(*args):
+        return jax.vmap(functools.partial(_KERNELS[name], **kw))(*args)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = _args(name, cfg, sds, batch=(cfg.max_batch,))
+    text = jax.jit(some_caller).lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = \S+ custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert [re.sub(r"\.\d+$", "", c) for c in calls] == [
+        "guided_score_" + name]
