@@ -4,7 +4,13 @@ The docid space is partitioned into tiles of ``tile_size`` documents. For
 each (term, tile) we store a CSR pointer into the term's posting run for that
 tile, plus tile-granular maxima of both weights (the block-max analogue).
 All query-time gathers are static-shaped: a term's postings inside one tile
-are fetched as a ``pad_len``-wide padded slice.
+are one contiguous run of the flat arrays. ``gather_tile`` fetches it as
+one ``pad_len``-wide window: the aligned ``FLAT_ROW``-lane rows that hold
+the run, gathered from a [rows, ``FLAT_ROW``] view of each flat array, then
+shifted to lane 0 and masked to the run's count. Every flat array ends in a
+tail of sentinel entries (``INVALID_DOC``, 0.0) that holds the rows of the
+last run's window (``with_sentinel_tail``): a row past the end would be
+clamped to an earlier one and return another term's postings.
 
 Arrays live as jnp devices arrays; the build is numpy host-side.
 """
@@ -21,6 +27,41 @@ from .align import MergedPostings
 
 INVALID_DOC = np.int32(2**31 - 1)
 
+# Lanes of one row of the [rows, FLAT_ROW] view that ``gather_tile`` fetches
+# posting windows through (a TPU vreg's lane count); flat arrays come in
+# whole (8, FLAT_ROW) tiles, so the view is the arrays' own memory order.
+FLAT_ROW = 128
+_FLAT_TILE = 8 * FLAT_ROW
+
+
+def _window_rows(pad_len: int) -> int:
+    """Rows of ``FLAT_ROW`` lanes that hold any ``pad_len``-wide window."""
+    return (pad_len + FLAT_ROW - 2) // FLAT_ROW + 1
+
+
+def flat_len(nnz: int, pad_len: int) -> int:
+    """Entries of a flat posting array of ``nnz`` postings with its
+    sentinel tail: the rows of any window that starts at or before ``nnz``,
+    rounded up to whole (8, ``FLAT_ROW``) tiles."""
+    need = nnz + (_window_rows(pad_len) + 1) * FLAT_ROW
+    return -(-need // _FLAT_TILE) * _FLAT_TILE
+
+
+def with_sentinel_tail(docids: np.ndarray, w_b: np.ndarray, w_l: np.ndarray,
+                       *, pad_len: int, nnz: int | None = None):
+    """The flat posting arrays padded with sentinels (``INVALID_DOC``, 0.0)
+    to ``flat_len(nnz, pad_len)`` entries. ``nnz`` defaults to their
+    length; ``shard_index`` passes its longest shard's, so every shard
+    comes out one length. Every flat array ``gather_tile`` reads is built
+    here."""
+    n = flat_len(len(docids) if nnz is None else nnz, pad_len)
+
+    def pad(a, fill):
+        out = np.full(n, fill, dtype=a.dtype)
+        out[:len(a)] = a
+        return out
+    return (pad(docids, INVALID_DOC), pad(w_b, 0), pad(w_l, 0))
+
 
 @dataclasses.dataclass
 class BlockedImpactIndex:
@@ -29,10 +70,12 @@ class BlockedImpactIndex:
     tile_size: int
     n_tiles: int
     pad_len: int          # max postings of one term inside one tile (padded)
-    # flat postings (term-major, docid-sorted within term)
-    docids: jax.Array     # [nnz] int32
-    w_b: jax.Array        # [nnz] f32
-    w_l: jax.Array        # [nnz] f32
+    nnz: int              # real postings (the flat arrays hold a tail more)
+    # flat postings (term-major, docid-sorted within term), then sentinels
+    # (INVALID_DOC, 0.0) up to flat_len(nnz, pad_len): see with_sentinel_tail
+    docids: jax.Array     # [flat_len] int32
+    w_b: jax.Array        # [flat_len] f32
+    w_l: jax.Array        # [flat_len] f32
     # per-(term, tile) structure
     tile_ptr: jax.Array   # [n_terms, n_tiles + 1] int32 (offsets into flat arrays)
     tile_max_b: jax.Array # [n_terms, n_tiles] f32
@@ -47,10 +90,6 @@ class BlockedImpactIndex:
     # Static tag dispatched on by the traversal executors (see
     # ``dispatch_gather``). The compressed index reports "q8".
     gather_kind = "fp32"
-
-    @property
-    def nnz(self) -> int:
-        return int(self.docids.shape[0])
 
     def gather_arrays(self) -> tuple[jax.Array, ...]:
         """Posting-side arrays consumed by ``dispatch_gather`` — the
@@ -155,13 +194,21 @@ def build_index(merged: MergedPostings, tile_size: int = 2048,
 
     ``doc_order`` (optional): permutation; new docid i <- original
     doc_order[i]. Results are mapped back via ``index.to_orig``.
+
+    The flat arrays get a sentinel tail (``with_sentinel_tail``: entries
+    ``INVALID_DOC``, 0.0) after the ``nnz`` real postings, so every row of
+    ``gather_tile``'s window of the last run exists; ``nnz`` counts the
+    real postings only.
     """
     lay = blocked_layout(merged, tile_size, pad_multiple, pad_cap, doc_order)
+    flat = with_sentinel_tail(lay["docids"], lay["w_b"], lay["w_l"],
+                              pad_len=lay["pad_len"])
     return BlockedImpactIndex(
         n_docs=lay["n_docs"], n_terms=lay["n_terms"], tile_size=tile_size,
         n_tiles=lay["n_tiles"], pad_len=lay["pad_len"],
-        docids=jnp.asarray(lay["docids"], dtype=jnp.int32),
-        w_b=jnp.asarray(lay["w_b"]), w_l=jnp.asarray(lay["w_l"]),
+        nnz=len(lay["docids"]),
+        docids=jnp.asarray(flat[0]), w_b=jnp.asarray(flat[1]),
+        w_l=jnp.asarray(flat[2]),
         tile_ptr=jnp.asarray(lay["tile_ptr"]),
         tile_max_b=jnp.asarray(lay["tile_max_b"]),
         tile_max_l=jnp.asarray(lay["tile_max_l"]),
@@ -182,16 +229,39 @@ def gather_tile(docids: jax.Array, w_b: jax.Array, w_l: jax.Array,
     [Nq]) scale each term's posting weights by the query weight — the
     executors' query-weighted gather; omitted = raw index weights. This
     is the single gather implementation shared by every traversal mode.
+
+    Each (term, tile) run is contiguous in the flat arrays: it starts at
+    ``tile_ptr[t, tile]`` and fits in ``_window_rows(pad_len)`` consecutive
+    rows of their [rows, ``FLAT_ROW``] view. Those rows come in one row
+    gather per array (not ``pad_len`` scalar gathers), are shifted left by
+    the start's lane in log2(``FLAT_ROW``) static steps, and are masked to
+    the run's count: a fixed handful of device ops whatever the number of
+    runs. The flat arrays must hold those rows past the last run
+    (``with_sentinel_tail``), or the last runs' rows would be clamped.
     """
+    n = docids.shape[0]
+    if docids.ndim != 1 or n % _FLAT_TILE or n < flat_len(0, pad_len):
+        raise ValueError(f"flat posting arrays of shape {docids.shape} lack "
+                         f"the sentinel tail of with_sentinel_tail")
     start = tile_ptr[q_terms, tile]            # [Nq]
     cnt = tile_ptr[q_terms, tile + 1] - start  # [Nq]
-    idx = start[:, None] + jnp.arange(pad_len, dtype=jnp.int32)[None, :]
+    lane0 = start % FLAT_ROW
+    rows = (start // FLAT_ROW)[:, None] + jnp.arange(
+        _window_rows(pad_len), dtype=start.dtype)[None, :]
+
+    def window(a):
+        w = jnp.take(a.reshape(n // FLAT_ROW, FLAT_ROW), rows, axis=0,
+                     mode="clip").reshape(start.shape[0], -1)
+        for b in range(FLAT_ROW.bit_length() - 1):
+            k = 1 << b
+            w = jnp.where((lane0 & k)[:, None] != 0, w[:, k:], w[:, :-k])
+        return w[:, :pad_len]
+
     mask = jnp.arange(pad_len, dtype=jnp.int32)[None, :] < cnt[:, None]
-    idx = jnp.where(mask, idx, 0)
-    d = jnp.take(docids, idx, mode="clip")
+    d, wb, wl = window(docids), window(w_b), window(w_l)
     offs = jnp.where(mask, d - tile * tile_size, -1).astype(jnp.int32)
-    wb = jnp.where(mask, jnp.take(w_b, idx, mode="clip"), 0.0)
-    wl = jnp.where(mask, jnp.take(w_l, idx, mode="clip"), 0.0)
+    wb = jnp.where(mask, wb, 0.0)
+    wl = jnp.where(mask, wl, 0.0)
     if qw_b is not None:
         wb = wb * qw_b[:, None]
     if qw_l is not None:

@@ -38,7 +38,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .index import BlockedImpactIndex
+from .index import BlockedImpactIndex, with_sentinel_tail
 
 
 @dataclasses.dataclass
@@ -134,6 +134,8 @@ def _pad_ptr(lp: np.ndarray, tps: int) -> np.ndarray:
 
 
 def _pad_flat(a: np.ndarray, n: int, fill=0) -> np.ndarray:
+    """Pad one shard's flat q8 leaf to ``n`` entries of ``fill``. (fp32
+    shards take ``with_sentinel_tail`` instead, the tail its gather reads.)"""
     out = np.full(n, fill, dtype=a.dtype)
     out[:len(a)] = a
     return out
@@ -201,14 +203,17 @@ def shard_index(index, n_shards: int) -> ShardedImpactIndex:
         tml_l.append(tml)
         base_l.append(t0 * tile_size)
 
-    # pad every shard's flat leaves (postings, and words for q8) to the
-    # max length, then stack each gather slot on the shard axis
-    n_leaves = len(shard_gather[0])
-    flat_slots = (0, 1, 2) if kind == "fp32" else (0, 1, 2)
+    # pad every shard's flat leaves (slots 0-2: postings, and words for
+    # q8) to one length (fp32: the longest shard's sentinel tail), then
+    # stack each gather slot on the shard axis
+    if kind == "fp32":
+        shard_gather = [(*with_sentinel_tail(*sg[:3], pad_len=index.pad_len,
+                                             nnz=int(nnz.max())), sg[3])
+                        for sg in shard_gather]
     gather = []
-    for i in range(n_leaves):
+    for i in range(len(shard_gather[0])):
         leaves = [sg[i] for sg in shard_gather]
-        if i in flat_slots:
+        if i < 3:
             m = max(1, max(len(a) for a in leaves))
             leaves = [_pad_flat(a, m) for a in leaves]
         gather.append(jnp.asarray(np.stack(leaves)))

@@ -139,3 +139,41 @@ def test_chunk_kernels_keep_their_names_in_the_compiled_program(
                        r'custom_call_target="tpu_custom_call"', text)
     assert [re.sub(r"\.\d+$", "", c) for c in calls] == [
         "guided_score_" + name]
+
+
+def test_long_route_gather_fetches_windows_not_scalars(one_chip,
+                                                       no_persistent_cache):
+    """The benchmark cell's long-route gather (8 batch rows x 8 tiles of a
+    chunk x 48 padded terms, ``pad_len`` 1,024 over a 1,105,228-passage
+    shard's 131.5 M postings) fetches each (term, tile) run as one window.
+    A ``gather`` with ``slice_sizes={1}`` over the flat posting arrays is
+    one random fetch per slot, 3.1 M per array and chunk step; only the
+    ``tile_ptr`` lookups (``slice_sizes={1,1}``) and the row gathers
+    (``slice_sizes={1,128}``) may be gathers. No ``while`` loop either: a
+    loop of one window copy a run writes three device events a trip, about
+    27,650 a chunk step, more than a profiler trace keeps for a window."""
+    import re
+    from repro.core.index import flat_len
+    from repro.core.traversal import _gather_tile
+    rows, tiles, nq, pad_len, tile_size = 8, 8, 48, 1024, 1024
+    nnz, n_terms, n_tiles = 131_500_000, 30522, 1080
+    f32, i32 = jnp.float32, jnp.int32
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    n = flat_len(nnz, pad_len)
+    flat = (sds((n,), i32), sds((n,), f32), sds((n,), f32),
+            sds((n_terms, n_tiles + 1), i32))
+
+    def chunk_gather(docids, w_b, w_l, tile_ptr, qt, qwb, qwl, tiles_chunk):
+        def row(qt, qwb, qwl, tc):
+            return jax.vmap(lambda t: _gather_tile(
+                docids, w_b, w_l, tile_ptr, qt, qwb, qwl, t,
+                pad_len=pad_len, tile_size=tile_size))(tc)
+        return jax.vmap(row)(qt, qwb, qwl, tiles_chunk)
+    args = (*flat, sds((rows, nq), i32), sds((rows, nq), f32),
+            sds((rows, nq), f32), sds((rows, tiles), i32))
+    text = jax.jit(chunk_gather).lower(*args).compile().as_text()
+    gathers = re.findall(r"= \S+ gather\(.*", text)
+    assert gathers, "the tile_ptr lookups should compile to gathers"
+    assert not [g for g in gathers if "slice_sizes={1}" in g]
+    assert " while(" not in text
