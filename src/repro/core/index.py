@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .align import MergedPostings
+from .plan import live_terms
 
 INVALID_DOC = np.int32(2**31 - 1)
 
@@ -227,8 +228,10 @@ def gather_tile(docids: jax.Array, w_b: jax.Array, w_l: jax.Array,
     Returns (offs [Nq, P] int32 local doc offsets, -1 where padded;
              wb, wl [Nq, P] f32 zero-padded). ``qw_b``/``qw_l`` (optional,
     [Nq]) scale each term's posting weights by the query weight — the
-    executors' query-weighted gather; omitted = raw index weights. This
-    is the single gather implementation shared by every traversal mode.
+    executors' query-weighted gather; omitted = raw index weights. Given
+    both, a slot with no weight on either side (padding) gets an empty
+    run. This is the single gather implementation shared by every
+    traversal mode.
 
     Each (term, tile) run is contiguous in the flat arrays: it starts at
     ``tile_ptr[t, tile]`` and fits in ``_window_rows(pad_len)`` consecutive
@@ -245,6 +248,8 @@ def gather_tile(docids: jax.Array, w_b: jax.Array, w_l: jax.Array,
                          f"the sentinel tail of with_sentinel_tail")
     start = tile_ptr[q_terms, tile]            # [Nq]
     cnt = tile_ptr[q_terms, tile + 1] - start  # [Nq]
+    if qw_b is not None and qw_l is not None:
+        cnt = jnp.where(live_terms(qw_b, qw_l), cnt, 0)
     lane0 = start % FLAT_ROW
     rows = (start // FLAT_ROW)[:, None] + jnp.arange(
         _window_rows(pad_len), dtype=start.dtype)[None, :]

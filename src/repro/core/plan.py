@@ -63,11 +63,32 @@ def plan_query(qt, qwb, qwl, sigma_b, sigma_l, alpha) -> QueryPlan:
                      sig_b[order], sig_l[order])
 
 
+def live_terms(qwb, qwl):
+    """Query slots that carry weight. A slot with no weight on either side
+    is padding: it matches no document (the gathers return it an empty
+    run) and no tile holds anything for it."""
+    return (qwb != 0) | (qwl != 0)
+
+
+def _bound_or_empty(plan: QueryPlan, raw_b, raw_l, ub):
+    """``ub`` where some live term has a posting in the tile (a nonzero
+    tile maximum), else -inf: an empty tile holds no match, so every
+    skip test and chunk bound passes it over even before the queues
+    fill. ``raw_b``/``raw_l`` are the unweighted tile maxima, [Nq, ...]."""
+    live = live_terms(plan.qwb, plan.qwl)
+    live = live.reshape(live.shape + (1,) * (raw_b.ndim - 1))
+    held = (live & ((raw_b > 0) | (raw_l > 0))).any(0)
+    return jnp.where(held, ub, -jnp.inf)
+
+
 def tile_upper_bounds(plan: QueryPlan, tile_max_b, tile_max_l, alpha):
-    """Per-tile alpha-combined global upper bounds: [n_tiles]."""
-    tm_b = plan.qwb[:, None] * tile_max_b[plan.qt, :]
-    tm_l = plan.qwl[:, None] * tile_max_l[plan.qt, :]
-    return combine(alpha, tm_b, tm_l).sum(0)
+    """Per-tile alpha-combined global upper bounds: [n_tiles]; -inf for a
+    tile that holds no posting of a live query term."""
+    raw_b = tile_max_b[plan.qt, :]
+    raw_l = tile_max_l[plan.qt, :]
+    ub = combine(alpha, plan.qwb[:, None] * raw_b,
+                 plan.qwl[:, None] * raw_l).sum(0)
+    return _bound_or_empty(plan, raw_b, raw_l, ub)
 
 
 def tile_schedule(plan: QueryPlan, tile_max_b, tile_max_l, alpha,
@@ -89,7 +110,7 @@ class ChunkSchedule(NamedTuple):
     #                      sentinel ``n_tiles`` pads the tail chunk and is
     #                      force-skipped by the executor (tile_valid False)
     chunk_ub: jax.Array  # [n_chunks] f32 max tile upper bound per chunk
-    #                      (-inf for all-padding chunks): the early-exit
+    #                      (-inf for chunks of padding or empty tiles): the early-exit
     #                      test operand. Descending by construction.
 
 
@@ -129,14 +150,18 @@ def chunk_schedule(plan: QueryPlan, tile_max_b, tile_max_l, alpha,
 def term_bounds(plan: QueryPlan, tile_max_b, tile_max_l, tile,
                 alpha, beta, bound_mode: str):
     """Bounds for one tile visit: per-term maxima under both combinations
-    plus the tile's global upper bound (the skip-test operand).
+    plus the tile's global upper bound (the skip-test operand; -inf for
+    a tile that holds no posting of a live query term).
 
     ``bound_mode='list'`` partitions with list-level maxima (paper
     MaxScore); ``'tile'`` with the tile-level block maxima.
     """
-    tm_b = plan.qwb * tile_max_b[plan.qt, tile]
-    tm_l = plan.qwl * tile_max_l[plan.qt, tile]
-    ub_gl = combine(alpha, tm_b, tm_l).sum()
+    raw_b = tile_max_b[plan.qt, tile]
+    raw_l = tile_max_l[plan.qt, tile]
+    tm_b = plan.qwb * raw_b
+    tm_l = plan.qwl * raw_l
+    ub_gl = _bound_or_empty(plan, raw_b, raw_l,
+                            combine(alpha, tm_b, tm_l).sum())
     if bound_mode == "tile":
         m_alpha = combine(alpha, tm_b, tm_l)
         m_beta = combine(beta, tm_b, tm_l)

@@ -205,7 +205,7 @@ def _score_tile_kernel_q(gt, plan: QueryPlan, tile, essential, prefix_beta,
     from ..kernels.guided_score import guided_score_tile_q
     with jax.named_scope("gather"):
         words, qb_row, ql_row, meta_i, meta_f = gather_tile_q_raw(
-            gt, plan.qt, tile, pad_len=pad_len)
+            gt, plan.qt, tile, plan.qwb, plan.qwl, pad_len=pad_len)
     with jax.named_scope("score"):
         out = guided_score_tile_q(
             words, qb_row, ql_row, meta_i, meta_f, plan.qwb, plan.qwl,
@@ -393,7 +393,8 @@ def _chunk_step_fused(idx_arrays, plan, carry, tiles_chunk,
         from ..index.compressed import gather_tile_q_raw
         with jax.named_scope("gather"):
             words, qbr, qlr, meta_i, meta_f = jax.vmap(
-                lambda t: gather_tile_q_raw(gt, plan.qt, t, pad_len=pad_len)
+                lambda t: gather_tile_q_raw(gt, plan.qt, t, plan.qwb,
+                                            plan.qwl, pad_len=pad_len)
             )(tiles_chunk)
         with jax.named_scope("score"):
             out = guided_score_chunk_q(

@@ -36,6 +36,7 @@ import numpy as np
 
 from ..core.align import MergedPostings
 from ..core.index import blocked_layout
+from ..core.plan import live_terms
 from . import codec
 
 
@@ -294,6 +295,8 @@ def gather_tile_q(gt: tuple, q_terms: jax.Array, tile: jax.Array,
      scale_b, zero_b, scale_l, zero_l) = gt
     start = tile_ptr[q_terms, tile]                     # [Nq]
     cnt = tile_ptr[q_terms, tile + 1] - start           # [Nq]
+    if qw_b is not None and qw_l is not None:
+        cnt = jnp.where(live_terms(qw_b, qw_l), cnt, 0)  # padding slots
     pw = pack_ptr[q_terms, tile]                        # [Nq]
     w = width[q_terms, tile].astype(jnp.int32)          # [Nq]
     f0 = first[q_terms, tile].astype(jnp.int32)         # [Nq]
@@ -332,8 +335,11 @@ def raw_words_len(pad_len: int) -> int:
 
 @partial(jax.jit, static_argnames=("pad_len",))
 def gather_tile_q_raw(gt: tuple, q_terms: jax.Array, tile: jax.Array,
-                      *, pad_len: int):
+                      qw_b: jax.Array | None = None,
+                      qw_l: jax.Array | None = None, *, pad_len: int):
     """Fetch *undecoded* per-term rows for the in-kernel Pallas decode.
+    Given both query weights, a padding slot (no weight on either side)
+    gets ``cnt`` 0, as in ``gather_tile_q``.
 
     Returns:
       words   [Nq, Wp] int32 — packed gap words (bitcast from uint32)
@@ -346,6 +352,8 @@ def gather_tile_q_raw(gt: tuple, q_terms: jax.Array, tile: jax.Array,
      scale_b, zero_b, scale_l, zero_l) = gt
     start = tile_ptr[q_terms, tile]
     cnt = tile_ptr[q_terms, tile + 1] - start
+    if qw_b is not None and qw_l is not None:
+        cnt = jnp.where(live_terms(qw_b, qw_l), cnt, 0)
     pw = pack_ptr[q_terms, tile]
     wp = raw_words_len(pad_len)
     widx = pw[:, None] + jnp.arange(wp, dtype=jnp.int32)[None, :]

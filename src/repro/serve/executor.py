@@ -55,8 +55,9 @@ import time
 from ..obs.spans import scope, scope_totals
 
 # the executor's own host work on a batch (``batch_host_ms``): every
-# span of the batch but the wait on the device
+# span of the batch but the wait on the device (``batch_device_ms``)
 HOST_SPANS = ("pick", "assemble", "dispatch", "finish", "deliver")
+DEVICE_SPAN = "device_wait"
 
 
 class ReplicaMap(dict):
@@ -162,7 +163,8 @@ class ExecutorPool:
         ``repro.assemble``, ``repro.dispatch``, ``repro.device_wait``,
         ``repro.finish``, ``repro.deliver`` on this thread, and idling
         reads ``repro.park``. The batch's time in all but the device
-        wait goes into the scheduler's ``batch_host_ms`` histogram."""
+        wait goes into the scheduler's ``batch_host_ms`` histogram, and
+        its device wait into its route's ``batch_device_ms``."""
         sched = self.scheduler
         retrievers = self.replicas.setdefault(slot, ReplicaMap())
         while True:
@@ -221,3 +223,6 @@ class ExecutorPool:
                 pass
             sched._hist_host.record(
                 sum(spent.get(n, 0.0) for n in HOST_SPANS) * 1e3)
+            _bucket, route_name, _tf = picked[0]
+            sched._route_hists[route_name]["batch_device_ms"].record(
+                spent.get(DEVICE_SPAN, 0.0) * 1e3)

@@ -66,7 +66,10 @@ result's assembly on the host), ``repro.deliver`` (``_deliver``), the
 executor's ``repro.park`` while no batch is due, and ``repro.gc`` for
 each collection of generation 1 or 2 while the scheduler runs. An
 executor's ``batch_host_ms`` is its time in pick, assemble, dispatch,
-finish and deliver for one batch. When ``SchedulerConfig.tracer``
+finish and deliver for one batch. ``by_route`` in ``stats()`` holds,
+per route, the ``queue_wait_ms`` of its requests and the
+``batch_device_ms`` of its batches (an executor's time in
+``repro.device_wait``). When ``SchedulerConfig.tracer``
 carries a real :class:`~repro.obs.spans.Tracer`, the scheduler also
 records one trace per request in its ring (admission -> queue ->
 execute spans, with the batch token, executor id and the traversal's
@@ -115,6 +118,8 @@ from .router import (RoutingPolicy, query_length, single_route,
 
 ADMISSION_POLICIES = ("block", "reject", "shed")
 CACHE_ADMISSIONS = ("always", "second_sight")
+# the histograms the scheduler keeps per route (``by_route`` in stats())
+ROUTE_HISTOGRAMS = ("queue_wait_ms", "batch_device_ms")
 
 
 class SchedulerSaturated(RuntimeError):
@@ -385,6 +390,12 @@ class AsyncRetrievalScheduler:
         self._hist_queue = self.metrics.histogram("queue_wait_ms")
         self._hist_service = self.metrics.histogram("batch_service_ms")
         self._hist_host = self.metrics.histogram("batch_host_ms")
+        # per route: the queue wait of its requests and the device wait
+        # of its batches (``by_route`` in ``stats()``)
+        self._route_hists = {
+            r.name: {h: self.metrics.histogram(f"{h}/{r.name}")
+                     for h in ROUTE_HISTOGRAMS}
+            for r in self.routing.all_routes}
         # lazily-built query featurizer (needs only index stats arrays);
         # invalidated by swap_index so features track the live index
         self._featurizer: QueryFeaturizer | None = None
@@ -1033,8 +1044,10 @@ class AsyncRetrievalScheduler:
                         self._cache.popitem(last=False)
                 self._counts["completed"] += 1
                 e.handle._complete(sliced, t_done=t_done)
-                self._hist_queue.record(
-                    max((rec.t_start - e.handle.t_submit) * 1e3, 0.0))
+                wait_ms = max((rec.t_start - e.handle.t_submit) * 1e3, 0.0)
+                self._hist_queue.record(wait_ms)
+                self._route_hists[route_name]["queue_wait_ms"].record(
+                    wait_ms)
                 if tracing:
                     self._trace_request(rec, e, sliced, t_done,
                                         degraded, executor_id)
@@ -1335,6 +1348,9 @@ class AsyncRetrievalScheduler:
         snap["queue_wait_ms"] = self._hist_queue.summary()
         snap["service_ms"] = self._hist_service.summary()
         snap["batch_host_ms"] = self._hist_host.summary()
+        snap["by_route"] = {
+            name: {h: hist.summary() for h, hist in hists.items()}
+            for name, hists in self._route_hists.items()}
         return snap
 
     def cache_clear(self) -> None:
