@@ -237,13 +237,13 @@ def kernels_phase(cfg: Config, corpus, fp32, q8, native: bool = True):
             np.testing.assert_allclose(outs["guided_score_chunk"][t],
                                        want if live else 0.0,
                                        rtol=1e-4, atol=1e-4)
-            np.testing.assert_allclose(outs["guided_score_chunk_q"][t, :5],
+            np.testing.assert_allclose(outs["guided_score_chunk_q"][t],
                                        want_q if live else 0.0,
                                        rtol=1e-4, atol=1e-4)
             if t == 0:
                 np.testing.assert_allclose(outs["guided_score_tile"], want,
                                            rtol=1e-4, atol=1e-4)
-                np.testing.assert_allclose(outs["guided_score_tile_q"][:5],
+                np.testing.assert_allclose(outs["guided_score_tile_q"],
                                            want_q, rtol=1e-4, atol=1e-4)
         print("  kernels agree with kernels/ref.py on gathered rows",
               flush=True)

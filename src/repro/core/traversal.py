@@ -153,33 +153,36 @@ def score_tile(offs, wb, wl, essential, prefix_beta, th_lo,
     return g_c, l_c, r_c, stats
 
 
+def _kernel_stats(out):
+    """Per-tile counters from a chunk of kernel outputs [C, 6, S]:
+    presence and postings touched from the per-slot posting count (row 5),
+    survivors and frozen docs from the masks. Offsets within a run are
+    distinct and padding matches no slot, so these equal ``score_tile``'s
+    scatter counts exactly. Returns [C, 4]."""
+    eval_mask = out[:, 3] > 0
+    rank_mask = out[:, 4] > 0
+    slot_cnt = out[:, 5]
+    return jnp.stack(
+        [(slot_cnt > 0).sum(1).astype(jnp.float32), out[:, 4].sum(1),
+         (rank_mask & ~eval_mask).sum(1).astype(jnp.float32),
+         slot_cnt.sum(1)], axis=1)
+
+
 def _score_tile_kernel(offs, wb, wl, essential, prefix_beta, th_lo,
                        alpha, beta, gamma, *, tile_size: int, kq: int):
     """Pallas guided_score kernel path (interpret mode on CPU): same
-    contract as ``score_tile``; the fused kernel returns G/L/R + masks."""
+    contract as ``score_tile``; the fused kernel returns G/L/R, the masks
+    and the per-slot posting count the stats read."""
     from ..kernels.guided_score import guided_score_tile
     out = guided_score_tile(offs, wb, wl, essential.astype(jnp.float32),
                             prefix_beta, th_lo, alpha, beta, gamma,
                             tile_size=tile_size,
                             block_s=min(512, tile_size))
-    g, l, r, eval_m, rank_m = out
+    g, l, r, eval_m, rank_m, _ = out
     eval_mask = eval_m > 0
     rank_mask = rank_m > 0
-
-    # The kernel reports only the post-partition masks; presence is
-    # re-derived from the gathered offsets exactly as score_tile counts it
-    # (one scatter over doc slots), so both paths report identical stats.
     with jax.named_scope("stats"):
-        valid = offs >= 0
-        S = tile_size
-        offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
-        cnt = jax.ops.segment_sum(valid.ravel().astype(jnp.float32),
-                                  offs_safe.ravel(), num_segments=S + 1)[:S]
-        present = cnt > 0
-        stats = jnp.stack([present.sum().astype(jnp.float32),
-                           rank_m.sum(),
-                           (rank_mask & ~eval_mask).sum().astype(jnp.float32),
-                           valid.sum().astype(jnp.float32)])
+        stats = _kernel_stats(out[None])[0]
     return (_tile_topk(g, eval_mask, kq), _tile_topk(l, eval_mask, kq),
             _tile_topk(r, rank_mask, kq), stats)
 
@@ -199,8 +202,7 @@ def _score_tile_kernel_q(gt, plan: QueryPlan, tile, essential, prefix_beta,
     rows go straight into ``guided_score_tile_q``, which delta-decodes the
     offsets and dequantizes the impacts in VMEM before the shared scatter/
     freeze passes. Same candidate contract as ``score_tile``; stats come
-    from the kernel's extra per-slot posting-count row (no host-side
-    decode, so decompression stays inside the memory-bound gather)."""
+    from the kernel's per-slot posting-count row (no second decode)."""
     from ..index.compressed import gather_tile_q_raw
     from ..kernels.guided_score import guided_score_tile_q
     with jax.named_scope("gather"):
@@ -212,17 +214,14 @@ def _score_tile_kernel_q(gt, plan: QueryPlan, tile, essential, prefix_beta,
             essential.astype(jnp.float32), prefix_beta, th_lo,
             alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
             block_s=min(512, tile_size))
-        g, l, r, eval_m, rank_m, slot_cnt = out
+        g, l, r, eval_m, rank_m, _ = out
         eval_mask = eval_m > 0
         rank_mask = rank_m > 0
         candidates = (_tile_topk(g, eval_mask, kq),
                       _tile_topk(l, eval_mask, kq),
                       _tile_topk(r, rank_mask, kq))
     with jax.named_scope("stats"):
-        stats = jnp.stack([(slot_cnt > 0).sum().astype(jnp.float32),
-                           rank_m.sum(),
-                           (rank_mask & ~eval_mask).sum().astype(jnp.float32),
-                           slot_cnt.sum()])
+        stats = _kernel_stats(out[None])[0]
     return (*candidates, stats)
 
 
@@ -402,11 +401,6 @@ def _chunk_step_fused(idx_arrays, plan, carry, tiles_chunk,
                 essential.astype(jnp.float32), prefix_beta, skip, th_lo,
                 alpha, beta, gamma, tile_size=tile_size, pad_len=pad_len,
                 block_s=min(512, tile_size))
-        with jax.named_scope("stats"):
-            # posting presence/counts come from the kernel's 6th output row
-            slot_cnt = out[:, 5]                              # [C, S]
-            present = (slot_cnt > 0).sum(1).astype(jnp.float32)
-            postings = slot_cnt.sum(1)
     else:
         docids, w_b, w_l, tile_ptr = gt
         with jax.named_scope("gather"):
@@ -420,28 +414,12 @@ def _chunk_step_fused(idx_arrays, plan, carry, tiles_chunk,
                 offs, wb, wl, essential.astype(jnp.float32), prefix_beta,
                 skip, th_lo, alpha, beta, gamma, tile_size=tile_size,
                 block_s=min(512, tile_size))
-        # Stats exactly as _score_tile_kernel derives them, chunk-vectorized:
-        # presence re-counted from the gathered offsets (one scatter/tile).
-        with jax.named_scope("stats"):
-            S = tile_size
-            valid = offs >= 0
-            offs_safe = jnp.where(valid, offs, S).astype(jnp.int32)
-
-            def present_one(v, o):
-                cnt = jax.ops.segment_sum(v.ravel().astype(jnp.float32),
-                                          o.ravel(), num_segments=S + 1)[:S]
-                return (cnt > 0).sum().astype(jnp.float32)
-            present = jax.vmap(present_one)(valid, offs_safe)
-            postings = valid.sum((1, 2)).astype(jnp.float32)
 
     g, l, r = out[:, 0], out[:, 1], out[:, 2]
     eval_mask = out[:, 3] > 0
     rank_mask = out[:, 4] > 0
     with jax.named_scope("stats"):
-        tile_stats = jnp.stack(
-            [present, out[:, 4].sum(1),
-             (rank_mask & ~eval_mask).sum(1).astype(jnp.float32),
-             postings], axis=1)                               # [C, 4]
+        tile_stats = _kernel_stats(out)                       # [C, 4]
 
     def merge_step(c, xs):
         gv, gi, lv, li, rv, ri, st = c

@@ -10,7 +10,9 @@ tile-scan engine:
   2. global-level essential-presence masking,
   3. the descending local-pruning freeze loop (beta-combined bound vs
      theta_Lo) with gated accumulation,
-  4. the three hybrid combinations Global/Local/Rank.
+  4. the three hybrid combinations Global/Local/Rank,
+  5. the per-slot posting count (the hit mask's column sums), from which
+     the traversal reads its presence and postings-touched counters.
 
 One pallas_call scores a chunk of tiles for one query; the grid is
 (tile-in-chunk, lane block of ``block_s`` docids). The single-tile entry
@@ -22,8 +24,9 @@ the accumulate.
 
 VMEM per grid cell (Nq=32, P=1024, block_s=512, f32): offs/wb/wl blocks
 3 * 128 KiB double-buffered, dense-row scratch 2 * 64 KiB, the [P, S_blk]
-select 2 MiB; the q8 decode adds its [P, P] prefix-sum select (4 MiB) and
-[Wp, P] word select (2 MiB). These grow with P, so the kernels refuse
+select 2 MiB, the [6, S_blk] output block 12 KiB double-buffered; the q8
+decode adds its [P, P] prefix-sum select (4 MiB) and [Wp, P] word select
+(2 MiB). These grow with P, so the kernels refuse
 ``pad_len > MAX_PAD_LEN``. ``tests/test_tpu_compile.py`` compiles every
 kernel for a v5e at the widths the chip smoke serves and at that bound.
 """
@@ -99,16 +102,15 @@ def _score_lanes(offs_row, wb_row, wl_row, ess, pbeta, lane, dense_b,
 
 
 def _write_out(out_ref, scal_ref, sb, sl, survive, alive, tot_cnt):
-    """Rows: Global, Local, RankScore, eval mask, rank mask and, for the
-    6-row q8 output, the per-slot posting count (stats source)."""
+    """Rows: Global, Local, RankScore, eval mask, rank mask and the
+    per-slot posting count (the traversal's stats source)."""
     alpha, beta, gamma = scal_ref[0, 1], scal_ref[0, 2], scal_ref[0, 3]
     out_ref[0, :] = (alpha * sb + (1.0 - alpha) * sl)[0]
     out_ref[1, :] = (beta * sb + (1.0 - beta) * sl)[0]
     out_ref[2, :] = (gamma * sb + (1.0 - gamma) * sl)[0]
     out_ref[3, :] = (survive * alive)[0]
     out_ref[4, :] = survive[0]
-    if out_ref.shape[0] == 6:
-        out_ref[5, :] = tot_cnt[0]
+    out_ref[5, :] = tot_cnt[0]
 
 
 def _lane_iota(block_s: int):
@@ -173,7 +175,9 @@ def guided_score_chunk(offs, wb, wl, essential, prefix_beta, skip, th_lo,
     cells. Inputs are chunk-stacked: offs/wb/wl [C, Nq, P], essential /
     prefix_beta [C, Nq] (per-tile planner outputs derived from the
     *chunk-start* thresholds — within the chunk that only loosens pruning,
-    so rank-safe configs stay exact). Returns [C, 5, tile_size].
+    so rank-safe configs stay exact). Returns [C, 6, tile_size]: Global,
+    Local, RankScore, eval mask, rank mask and the per-slot posting count
+    (all zero for a skipped tile).
 
     ``interpret=None`` resolves via :func:`pallas_env.default_interpret`:
     native lowering on TPU backends, the Python interpreter elsewhere.
@@ -191,8 +195,8 @@ def guided_score_chunk(offs, wb, wl, essential, prefix_beta, skip, th_lo,
         grid=(n_chunk, tile_size // block_s),
         in_specs=[_smem(), _smem(), _smem(), _smem(),  # scal, ess, pbeta, skip
                   rows, rows, rows],                   # offs, wb, wl
-        out_specs=pl.BlockSpec((None, 5, block_s), lambda c, s: (c, 0, s)),
-        out_shape=jax.ShapeDtypeStruct((n_chunk, 5, tile_size), jnp.float32),
+        out_specs=pl.BlockSpec((None, 6, block_s), lambda c, s: (c, 0, s)),
+        out_shape=jax.ShapeDtypeStruct((n_chunk, 6, tile_size), jnp.float32),
         scratch_shapes=[pltpu.VMEM((nq, block_s), jnp.float32),
                         pltpu.VMEM((nq, block_s), jnp.float32)],
         compiler_params=_SEQUENTIAL,
@@ -206,7 +210,7 @@ def guided_score_tile(offs, wb, wl, essential, prefix_beta, th_lo,
                       alpha, beta, gamma, *, tile_size: int,
                       block_s: int = 512, interpret: bool | None = None):
     """Score one (query, tile) pair: ``guided_score_chunk`` on a
-    one-tile chunk. Returns [5, tile_size]."""
+    one-tile chunk. Returns [6, tile_size]."""
     return guided_score_chunk(
         offs[None], wb[None], wl[None], essential[None], prefix_beta[None],
         jnp.zeros((1,), jnp.int32), th_lo, alpha, beta, gamma,
@@ -230,8 +234,8 @@ def guided_score_tile(offs, wb, wl, essential, prefix_beta, th_lo,
 #   w_j     = (zero + scale * q_j) * qw           (<= exact tile max * qw
 #             by codec construction, so planner bounds stay valid).
 #
-# Output gains a 6th row — per-slot posting count — so the caller derives
-# presence/postings-touched stats without a second (host-side) decode.
+# The output rows are the fp32 kernel's, so the caller reads the per-slot
+# posting count without a second decode.
 # ---------------------------------------------------------------------------
 
 
@@ -317,8 +321,8 @@ def guided_score_chunk_q(words, qb_row, ql_row, meta_i, meta_f, qw_b, qw_l,
     [C, 3, Nq], meta_f [C, 4, Nq]) plus the per-term query weights
     (applied after dequantization, preserving the fp32 path's
     ``fl(dequant) * qw <= fl(tile_max * qw)`` bound); per-tile planner
-    inputs as ``guided_score_chunk``. Returns [C, 6, tile_size] (row 5 =
-    per-slot posting count)."""
+    inputs as ``guided_score_chunk``. Returns [C, 6, tile_size], the rows
+    of ``guided_score_chunk``."""
     if interpret is None:
         interpret = default_interpret()
     n_chunk, nq, wp = words.shape

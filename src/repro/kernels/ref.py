@@ -7,7 +7,7 @@ import jax.numpy as jnp
 
 def guided_score_tile_ref(offs, wb, wl, essential, prefix_beta, th_lo,
                           alpha, beta, gamma, *, tile_size: int):
-    """Oracle for kernels.guided_score.guided_score_tile -> [5, tile_size]."""
+    """Oracle for kernels.guided_score.guided_score_tile -> [6, tile_size]."""
     nq, p = offs.shape
     S = tile_size
     valid = offs >= 0
@@ -44,6 +44,7 @@ def guided_score_tile_ref(offs, wb, wl, essential, prefix_beta, th_lo,
         gamma * sb + (1 - gamma) * sl,
         (survive & alive).astype(jnp.float32),
         survive.astype(jnp.float32),
+        cnt.sum(0),
     ])
 
 

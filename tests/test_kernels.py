@@ -11,11 +11,12 @@ from repro.kernels.guided_score import guided_score_chunk, guided_score_tile
 
 
 def _tile_inputs(rng, nq, p, tile_size, density=0.5):
+    """Sorted distinct offsets with -1 padding; row 0's run fills pad_len."""
     n_valid = int(p * density)
     offs = np.full((nq, p), -1, np.int32)
     for i in range(nq):
-        offs[i, :n_valid] = np.sort(
-            rng.choice(tile_size, size=n_valid, replace=False))
+        n = p if i == 0 else n_valid
+        offs[i, :n] = np.sort(rng.choice(tile_size, size=n, replace=False))
     wb = (rng.random((nq, p)) * 3).astype(np.float32) * (offs >= 0)
     wl = (rng.random((nq, p)) * 5).astype(np.float32) * (offs >= 0)
     return jnp.asarray(offs), jnp.asarray(wb), jnp.asarray(wl)
@@ -34,8 +35,19 @@ def test_guided_score_matches_ref(nq, p, tile_size, block_s):
             jnp.float32(1.0), jnp.float32(0.3), jnp.float32(0.05))
     out_k = guided_score_tile(*args, tile_size=tile_size, block_s=block_s)
     out_r = ref.guided_score_tile_ref(*args, tile_size=tile_size)
+    assert out_k.shape == (6, tile_size)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-5)
+    _assert_slot_counts(out_k[5], out_r[5], offs, tile_size)
+
+
+def _assert_slot_counts(row5, row5_ref, offs, tile_size):
+    """Row 5 is the per-slot posting count, exactly: equal to the oracle
+    and to a count of the valid offsets."""
+    offs = np.asarray(offs)
+    want = np.bincount(offs[offs >= 0], minlength=tile_size)
+    np.testing.assert_array_equal(np.asarray(row5), np.asarray(row5_ref))
+    np.testing.assert_array_equal(np.asarray(row5), want)
 
 
 @pytest.mark.parametrize("alpha,beta,gamma,th_lo", [
@@ -109,7 +121,7 @@ def test_guided_score_chunk_matches_per_tile(n_chunk, nq, p, tile_size,
             jnp.float32(0.05))
     out = guided_score_chunk(offs, wb, wl, essential, prefix_beta, skip,
                              *scal, tile_size=tile_size, block_s=block_s)
-    assert out.shape == (n_chunk, 5, tile_size)
+    assert out.shape == (n_chunk, 6, tile_size)
     for c in range(n_chunk):
         if int(skip[c]):
             np.testing.assert_array_equal(np.asarray(out[c]), 0.0)
@@ -120,6 +132,10 @@ def test_guided_score_chunk_matches_per_tile(n_chunk, nq, p, tile_size,
             np.testing.assert_allclose(np.asarray(out[c]),
                                        np.asarray(per_tile),
                                        rtol=1e-5, atol=1e-5)
+            want = ref.guided_score_tile_ref(
+                offs[c], wb[c], wl[c], essential[c], prefix_beta[c],
+                *scal, tile_size=tile_size)
+            _assert_slot_counts(out[c, 5], want[5], offs[c], tile_size)
 
 
 def test_guided_score_chunk_all_skipped_is_zero():

@@ -113,6 +113,29 @@ def test_chunk_kernels_compile_at_max_pad_len(one_chip, no_persistent_cache,
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                  sharding=one_chip)
     args = _args(name, cfg, sds, batch=(cfg.max_batch,))
+    assert jax.eval_shape(fn, *args).shape == (
+        cfg.max_batch, cfg.chunk_tiles, 6, MAX_PAD_LEN)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["tile", "chunk"])
+@pytest.mark.parametrize("nq", [48, 16])
+def test_fp32_kernels_compile_at_served_widths(one_chip, no_persistent_cache,
+                                               chip_smoke, name, nq):
+    """The fp32 kernels at the benchmark cells' long-route widths (48 and
+    16 terms, ``pad_len`` 1,024, 8 batch rows of 8-tile chunks) compile
+    natively with the six-row output the traversal's stats read."""
+    import dataclasses
+    cfg = dataclasses.replace(chip_smoke.Config(), query_terms=nq,
+                              tile_size=1024, chunk_tiles=8, max_batch=8)
+    fn = jax.vmap(functools.partial(_KERNELS[name], tile_size=1024,
+                                    interpret=False))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = _args(name, cfg, sds, batch=(8,))
+    want = (8, 8, 6, 1024) if name == "chunk" else (8, 6, 1024)
+    assert jax.eval_shape(fn, *args).shape == want
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

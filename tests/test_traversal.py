@@ -291,6 +291,30 @@ def test_chunked_fused_guided_tolerance(small_corpus):
     assert overlap > 0.9
 
 
+@pytest.mark.parametrize("traversal", ["chunked_fused", "chunked"],
+                         ids=["chunk_kernel", "tile_kernel"])
+@pytest.mark.parametrize("preset", ["rank_safe", "guided"])
+def test_kernel_stats_bit_identical_to_jnp_counts(setup, preset, traversal):
+    """The kernel paths read ``docs_present`` and ``postings_touched`` from
+    the kernel's per-slot posting count; they must equal the jnp scorer's
+    scatter counts bit for bit. At one tile a chunk the fused kernel's
+    chunk-start thresholds are the per-tile ones, so its trajectory is the
+    jnp chunk loop's."""
+    corpus, merged, index = setup
+    p = (twolevel.original(gamma=0.2) if preset == "rank_safe"
+         else twolevel.fast()).replace(chunk_tiles=1)
+    q = (corpus.queries, corpus.q_weights_b, corpus.q_weights_l)
+    ck = retrieve_batched(index, *q, p, traversal="chunked")
+    ker = retrieve_batched(index, *q, p, traversal=traversal,
+                           use_kernel=True)
+    np.testing.assert_array_equal(ck.ids, ker.ids)
+    np.testing.assert_allclose(ck.scores, ker.scores, rtol=1e-6)
+    for key in PARITY_STATS + ("chunks_dispatched",):
+        np.testing.assert_array_equal(ck.stats[key], ker.stats[key])
+    assert (ker.stats["docs_present"] > 0).all()
+    assert (ker.stats["postings_touched"] >= ker.stats["docs_present"]).all()
+
+
 def test_chunked_rejects_unknown_traversal(setup):
     corpus, merged, index = setup
     with pytest.raises(ValueError, match="traversal"):
