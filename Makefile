@@ -1,6 +1,6 @@
 # Test lanes. `make test` is the pre-review gate: the fast lane first
 # (collection regressions surface in seconds), then the slow lane
-# (subprocess dry-run compiles, multi-device collectives).
+# (multi-device subprocess collectives, the 2^20-doc build).
 PY      := python
 PYTEST  := PYTHONPATH=src $(PY) -m pytest -q
 
@@ -38,7 +38,7 @@ test-serve:
 # quickest signal when touching serve/health.py, serve/faults.py, or
 # the scheduler's fault paths).
 test-faults:
-	$(PYTEST) tests/test_faults.py
+	$(PYTEST) tests/test_serve_faults.py
 
 # Multi-worker saturation soaks: executor pools under overload with
 # shedding and concurrent submitters (threaded, timing-sensitive — kept

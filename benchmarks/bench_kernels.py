@@ -12,8 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.embedding_bag import embedding_bag
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.guided_score import guided_score_tile
 
 from .common import emit
@@ -46,26 +44,4 @@ def run(out) -> None:
         guided_score_tile(*args, tile_size=s, block_s=512)
         - ref.guided_score_tile_ref(*args, tile_size=s))))
     out(emit("kernels/guided_score/nq16_p256_s1024", t_k,
-             {"ref_ms": t_r, "max_err": err}))
-    # flash attention
-    q = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 256, 64)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 256, 64)), jnp.float32)
-    t_k = _time(lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
-    t_r = _time(lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=True),
-                q, k, v)
-    err = float(jnp.max(jnp.abs(flash_attention(q, k, v, causal=True)
-                                - ref.flash_attention_ref(q, k, v,
-                                                          causal=True))))
-    out(emit("kernels/flash_attention/h4_s256_d64", t_k,
-             {"ref_ms": t_r, "max_err": err}))
-    # embedding bag
-    tab = jnp.asarray(rng.standard_normal((4096, 64)), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, 4096, (64, 8)), jnp.int32)
-    w = jnp.asarray(rng.random((64, 8)), jnp.float32)
-    t_k = _time(lambda t, i, w: embedding_bag(t, i, w, block_b=8), tab, idx, w)
-    t_r = _time(ref.embedding_bag_ref, tab, idx, w)
-    err = float(jnp.max(jnp.abs(embedding_bag(tab, idx, w, block_b=8)
-                                - ref.embedding_bag_ref(tab, idx, w))))
-    out(emit("kernels/embedding_bag/v4096_b64_l8", t_k,
              {"ref_ms": t_r, "max_err": err}))

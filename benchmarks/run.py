@@ -21,7 +21,7 @@ from repro.launch.compile_cache import enable_compile_cache
 MODULES = ("figure1", "table2", "table3", "table4", "figure3",
            "table6_suite", "table7_bmw", "table8_qlen", "dense_transfer",
            "bench_kernels", "sharded_scaling", "retrieval_smoke",
-           "serving_bench", "quality_bench", "roofline", "million_doc")
+           "serving_bench", "quality_bench", "million_doc")
 
 
 def main() -> None:

@@ -1,11 +1,7 @@
-# Distributed substrate: sharding rules, host-level collectives, gradient
-# compression, straggler handling. Everything here is mesh-shape agnostic and
-# runs unchanged on the 1-device CPU test mesh, the 8-device subprocess mesh,
-# and the 512-device production dry-run meshes.
+# Distributed substrate: host-level ring collectives and gradient
+# compression. Mesh-shape agnostic: runs unchanged on the 1-device CPU test
+# mesh and on multi-device meshes.
 from .collectives import (hierarchical_all_reduce, reduce_scatter,  # noqa: F401
                           ring_all_gather, ring_all_reduce, ring_gather_stack)
 from .compression import (CompressionConfig, compress_with_feedback,  # noqa: F401
                           compression_ratio, init_error_feedback, topk_sparsify)
-from .sharding import (activation_rules, input_shardings,  # noqa: F401
-                       opt_shardings, param_shardings)
-from .straggler import StragglerConfig, StragglerMonitor  # noqa: F401

@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels for the compute hot-spot the paper optimizes: guided
+# tile scoring (guided_score.py), with its pure-jnp oracle in ref.py.

@@ -46,30 +46,3 @@ def guided_score_tile_ref(offs, wb, wl, essential, prefix_beta, th_lo,
         survive.astype(jnp.float32),
         cnt.sum(0),
     ])
-
-
-def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        sm_scale: float | None = None, kv_offset: int = 0):
-    """Oracle for kernels.flash_attention (GQA + causal + offset)."""
-    h, sq, d = q.shape
-    hkv, skv, _ = k.shape
-    group = h // hkv
-    if sm_scale is None:
-        sm_scale = 1.0 / (d ** 0.5)
-    kg = jnp.repeat(k, group, axis=0)
-    vg = jnp.repeat(v, group, axis=0)
-    s = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
-                   kg.astype(jnp.float32)) * sm_scale
-    if causal:
-        q_pos = kv_offset + jnp.arange(sq)[:, None]
-        k_pos = jnp.arange(skv)[None, :]
-        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,hkd->hqd", p,
-                      vg.astype(jnp.float32)).astype(q.dtype)
-
-
-def embedding_bag_ref(table, indices, weights):
-    """Oracle for kernels.embedding_bag: weighted bag sum via take."""
-    rows = jnp.take(table, indices, axis=0)        # [B, L, D]
-    return (rows * weights[..., None]).sum(axis=1).astype(table.dtype)

@@ -1,16 +1,15 @@
 """Decoder-style transformer LM: RoPE / GQA / SwiGLU / RMSNorm, optional MoE
 (sort-based static-capacity dispatch, EP-shardable), optional bidirectional
-mode + learned positions (BERT4Rec reuses this), optional SPLADE-style sparse
-head (the learned sparse encoder role for the retrieval core).
+mode + learned positions, optional SPLADE-style sparse head (the learned
+sparse encoder role for the retrieval core).
 
-Layers are scanned with stacked parameters — HLO stays O(1) in depth, which
-keeps 48-layer x 512-device dry-run compiles tractable. Sharding is injected
-via ``Rules`` (logical-axis -> mesh-axes) through with_sharding_constraint;
-`None` rules mean single-device execution (tests, smoke configs).
+Layers are scanned with stacked parameters — HLO stays O(1) in depth.
+Sharding is injected via ``Rules`` (logical-axis -> mesh-axes) through
+with_sharding_constraint; `None` rules mean single-device execution (tests,
+smoke configs).
 
 Mixed precision: parameters are stored in ``param_dtype`` (fp32 by default),
-compute runs in ``compute_dtype`` (bf16 by default) — the roofline counts
-bf16 FLOPs.
+compute runs in ``compute_dtype`` (bf16 by default).
 """
 from __future__ import annotations
 
@@ -51,7 +50,6 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32
     remat: bool = True
     remat_policy: str = "full"  # full|dots (full = recompute layer in bwd)
-    unroll: bool = False       # unroll the layer scan (dry-run cost probes)
     attn_chunk: int = 0        # >0: flash-style q-chunked attention
     kv_quant: bool = False     # int8 KV cache (per-position scales)
 
@@ -201,15 +199,12 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _attention(q, k, v, causal, q_offset, chunk: int = 0,
-               unroll: bool = False):
+def _attention(q, k, v, causal, q_offset, chunk: int = 0):
     """q: [B, Sq, H, Dh]; k, v: [B, Skv, Hkv, Dh] (GQA via reshape).
 
     ``chunk`` > 0 scans over q blocks (flash-style): scores for one block
     only are ever materialized — O(Sq/chunk) passes, O(B*chunk*Skv) memory
-    instead of O(B*Sq*Skv). The Pallas kernel is the real-TPU analogue.
-    ``unroll`` unrolls the chunk scan (dry-run cost probes: XLA counts
-    while bodies once — unrolling keeps FLOP/byte accounting exact).
+    instead of O(B*Sq*Skv).
     """
     b, sq, h, dh = q.shape
     if chunk and sq > chunk and sq % chunk == 0:
@@ -222,8 +217,7 @@ def _attention(q, k, v, causal, q_offset, chunk: int = 0,
             return carry, _attention(qi, k, v, causal, off)
 
         _, out = jax.lax.scan(body, None,
-                              (qc, jnp.arange(nc, dtype=jnp.int32)),
-                              unroll=nc if unroll else 1)
+                              (qc, jnp.arange(nc, dtype=jnp.int32)))
         return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, dh)
     skv, hkv = k.shape[1], k.shape[2]
     group = h // hkv
@@ -368,8 +362,7 @@ def _layer(cfg: TransformerConfig, rules: Rules, x, lp, positions, cache=None,
         q_offset = jnp.full((b,), cache_len, jnp.int32)
     else:
         q_offset = jnp.zeros((b,), jnp.int32)
-    o = _attention(q, k, v, cfg.causal, q_offset, cfg.attn_chunk,
-                   unroll=cfg.unroll)
+    o = _attention(q, k, v, cfg.causal, q_offset, cfg.attn_chunk)
     o = jnp.einsum("bse,ed->bsd", o.reshape(b, s, h * dh),
                    rules.w(lp["wo"], cd))
     x = x + o
@@ -427,10 +420,8 @@ def forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                                   policy=jax.checkpoint_policies.dots_saveable)
         else:  # "full": save only layer inputs, recompute the layer in bwd
             body = jax.checkpoint(scan_body)
-    unroll = cfg.n_layers if cfg.unroll else 1
     if cache is None:
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), lp_stack,
-                                   unroll=unroll)
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), lp_stack)
         new_cache = None
     else:
         if cfg.kv_quant:
@@ -438,8 +429,7 @@ def forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                              cache["v_scale"]))
         else:
             xs = (lp_stack, (cache["k"], cache["v"]))
-        (x, aux), kvs = jax.lax.scan(body, (x, jnp.float32(0.0)), xs,
-                                     unroll=unroll)
+        (x, aux), kvs = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
         new_cache = {"k": kvs[0], "v": kvs[1]}
         if cfg.kv_quant:
             new_cache["k_scale"] = kvs[2]

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests run on 1 CPU device;
-only launch/dryrun.py (and its subprocess test) uses 512 fake devices."""
+multi-device tests start a subprocess with a fake-device count."""
 import importlib.util
 import pathlib
 import sys

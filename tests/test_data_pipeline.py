@@ -1,56 +1,10 @@
-"""Data pipeline: determinism (resume-safety), neighbor sampler shape/degree
-invariants, molecule batch physics proxy."""
+"""Data pipeline: the encoder-training pair stream."""
 import numpy as np
 
-from repro.data.stream import (GraphStore, lm_batch, molecule_batch,
-                               pair_batch, recsys_batch)
-
-
-def test_lm_batch_deterministic_per_step():
-    a = lm_batch(5, batch=4, seq=16, vocab=100)
-    b = lm_batch(5, batch=4, seq=16, vocab=100)
-    np.testing.assert_array_equal(a["tokens"], b["tokens"])
-    c = lm_batch(6, batch=4, seq=16, vocab=100)
-    assert not np.array_equal(a["tokens"], c["tokens"])
-    assert a["tokens"].max() < 100
-    # targets are next-token shifted
-    raw_a = np.asarray(a["tokens"])
-    np.testing.assert_array_equal(np.asarray(a["targets"])[:, :-1],
-                                  raw_a[:, 1:])
+from repro.data.stream import pair_batch
 
 
 def test_pair_batch_salient_terms_shared():
     b = pair_batch(3, batch=4, seq=16, vocab=100, n_rel_terms=4)
     np.testing.assert_array_equal(np.asarray(b["query"])[:, :4],
                                   np.asarray(b["doc_pos"])[:, :4])
-
-
-def test_graph_store_sampler_shapes_and_locality():
-    store = GraphStore(n_nodes=1000, n_edges=8000, d_feat=16, n_classes=5)
-    sub = store.sample(0, batch_nodes=32, fanouts=(5, 3))
-    n = sub["x"].shape[0]
-    assert sub["x"].shape == (n, 16)
-    assert sub["edge_src"].max() < n and sub["edge_dst"].max() < n
-    assert sub["edge_src"].shape == sub["edge_dst"].shape
-    assert sub["train_mask"].sum() == 32  # seeds masked for loss
-    # deterministic per step
-    sub2 = store.sample(0, batch_nodes=32, fanouts=(5, 3))
-    np.testing.assert_array_equal(sub["edge_src"], sub2["edge_src"])
-    sub3 = store.sample(1, batch_nodes=32, fanouts=(5, 3))
-    assert sub3["x"].shape[0] > 0
-
-
-def test_molecule_batch_energy_depends_on_geometry():
-    a = molecule_batch(0, batch=4, atoms=8, edges=16, n_types=10)
-    assert np.all(np.isfinite(np.asarray(a["energy"])))
-    assert np.asarray(a["z"]).min() >= 1
-
-
-def test_recsys_batches():
-    from repro.models.recsys import DINConfig, DLRMConfig
-    d = recsys_batch(2, kind="dlrm", cfg=DLRMConfig(vocab_per_field=50),
-                     batch=8)
-    assert d["sparse"].shape == (8, 26, 1)
-    assert int(d["sparse"].max()) < 50
-    d = recsys_batch(2, kind="din", cfg=DINConfig(n_items=30), batch=8)
-    assert d["hist"].shape == (8, 100)

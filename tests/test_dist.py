@@ -1,5 +1,5 @@
-"""repro.dist unit tests: sharding policy placement, collective identities,
-compression accounting, straggler recovery. Multi-device collective
+"""repro.dist unit tests: collective identities and compression
+accounting. Multi-device collective
 correctness runs in a subprocess with 8 fake devices (slow lane) — the main
 test process must keep its single CPU device."""
 import json
@@ -15,87 +15,6 @@ import pytest
 from repro.dist.compression import (CompressionConfig, compress_with_feedback,
                                     compression_ratio, init_error_feedback,
                                     topk_sparsify)
-from repro.dist.sharding import (activation_rules, input_shardings,
-                                 opt_shardings, param_shardings)
-from repro.dist.straggler import StragglerConfig, StragglerMonitor
-
-
-# -- sharding policy ---------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def test_activation_rules_tp_vs_fsdp(mesh11):
-    tp = activation_rules(mesh11, "tp")
-    assert tp.batch == ("data",) and tp.heads == "model"
-    assert tp.vocab == "model" and not tp.gather_weights
-    fsdp = activation_rules(mesh11, "fsdp")
-    assert fsdp.batch == ("data",) and fsdp.heads is None
-    assert fsdp.gather_weights
-
-
-def test_lm_param_placement(mesh11):
-    from repro.configs import get_arch
-    from repro.launch.steps import state_specs
-    arch = get_arch("internlm2-1.8b")
-    cfg = arch.config()
-    st = state_specs(arch, "train_4k", cfg)
-    p_sh = param_shardings("lm", cfg, mesh11, st["params"], "tp")
-    specs = {k: v.spec for k, v in p_sh["layers"].items()}
-    # projections shard the head/ffn dim; return projections the
-    # contraction dim; norms replicate
-    assert specs["wq"][-1] == "model" and specs["w_up"][-1] == "model"
-    assert specs["wo"][-2] == "model" and specs["w_down"][-2] == "model"
-    assert all(s is None for s in specs["attn_norm"])
-    assert p_sh["embed"].spec[0] == "model"
-    # optimizer moments inherit the param layout; step replicates
-    o_sh = opt_shardings(p_sh)
-    assert o_sh["m"]["layers"]["wq"].spec == specs["wq"]
-    assert o_sh["step"].spec == jax.sharding.PartitionSpec()
-
-
-def test_fsdp_shards_params_over_all_axes(mesh11):
-    from repro.configs import get_arch
-    from repro.launch.steps import state_specs
-    arch = get_arch("internlm2-1.8b")
-    cfg = arch.config()
-    st = state_specs(arch, "train_4k", cfg)
-    p_sh = param_shardings("lm", cfg, mesh11, st["params"], "fsdp")
-    spec = p_sh["layers"]["wq"].spec
-    assert ("data", "model") in tuple(spec), spec
-
-
-def test_input_shardings_batch_and_candidates(mesh11):
-    from repro.configs import get_arch
-    from repro.configs.shapes import input_specs
-    arch = get_arch("two-tower-retrieval")
-    cfg = arch.config()
-    spec = input_specs(arch, "retrieval_cand", cfg)
-    in_sh = input_shardings("recsys", cfg, mesh11, spec, "tp")
-    # 1M-candidate axis spans the whole mesh; the 1-row user replicates
-    assert in_sh["cand_emb"].spec[0] == ("data", "model")
-    assert all(s is None for s in in_sh["user_feats"].spec)
-
-
-def test_non_divisible_dims_replicate():
-    """Placement rules at a real tp_size=2 (pure functions, no mesh):
-    dims that the axis size does not divide must replicate."""
-    from repro.dist.sharding import _lm_param_spec, _recsys_param_spec
-    P = jax.sharding.PartitionSpec
-    # 13 % 2 != 0: projection replicates instead of sharding unevenly
-    assert _lm_param_spec("wq", (7, 13), "model", 2) == P(None, None)
-    assert _lm_param_spec("wq", (7, 16), "model", 2) == P(None, "model")
-    # contraction-dim rule for the return projection
-    assert _lm_param_spec("wo", (4, 16, 13), "model", 2) == \
-        P(None, "model", None)
-    assert _lm_param_spec("embed", (92543, 64), "model", 2) == P(None, None)
-    # table rows shard only when divisible
-    assert _recsys_param_spec("item_embed", (2_000_000, 128), "model", 2) \
-        == P("model", None)
-    assert _recsys_param_spec("item_embed", (2_000_001, 128), "model", 2) \
-        == P(None, None)
 
 
 # -- collectives (single device: identity) -----------------------------------
@@ -215,28 +134,3 @@ def test_compression_ratio_scales_with_bits():
     r8 = compression_ratio(g)
     r4 = compression_ratio(g, CompressionConfig(residual_bits=4))
     assert r4 > r8 > 3.5
-
-
-# -- straggler ---------------------------------------------------------------
-
-def test_straggler_recovers_after_speedup():
-    mon = StragglerMonitor(4, 4, StragglerConfig(patience=2, evict_after=50))
-    for step in range(6):
-        out = mon.report(step, np.array([1.0, 1.0, 1.0, 4.0]))
-    assert mon.degraded[3] and out["assignments"][3] == 2
-    for step in range(6, 30):
-        out = mon.report(step, np.array([1.0, 1.0, 1.0, 1.0]))
-    assert not mon.degraded[3]
-    assert out["assignments"][3] == 4            # restored
-    assert out["assignments"].sum() == 16
-    assert out["evict"] == []
-
-
-def test_straggler_work_conserved_with_many_degraded():
-    mon = StragglerMonitor(8, 4, StragglerConfig(patience=1, evict_after=99))
-    d = np.ones(8)
-    d[[2, 5, 6]] = 10.0
-    for step in range(4):
-        out = mon.report(step, d)
-    assert out["assignments"].sum() == 32
-    assert all(out["assignments"][i] == 2 for i in (2, 5, 6))

@@ -5,8 +5,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.embedding_bag import embedding_bag
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.guided_score import guided_score_chunk, guided_score_tile
 
 
@@ -166,73 +164,3 @@ def test_guided_score_refuses_runs_wider_than_max_pad_len():
                 tile_size=p),
             jax.ShapeDtypeStruct((1, 2, p), jnp.int32), rows, rows,
             per_tile, per_tile)
-
-
-@pytest.mark.parametrize("h,hkv,sq,skv,d,causal,off", [
-    (4, 4, 128, 128, 64, True, 0),
-    (8, 2, 128, 256, 64, True, 128),   # GQA + decode-style offset
-    (4, 1, 64, 128, 128, False, 0),    # MQA, bidirectional
-    (2, 2, 256, 256, 32, True, 0),
-])
-def test_flash_attention_matches_ref(h, hkv, sq, skv, d, causal, off):
-    rng = np.random.default_rng(h * 100 + skv)
-    q = jnp.asarray(rng.standard_normal((h, sq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((hkv, skv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((hkv, skv, d)), jnp.float32)
-    out_k = flash_attention(q, k, v, causal=causal, kv_offset=off,
-                            block_q=64, block_k=64)
-    out_r = ref.flash_attention_ref(q, k, v, causal=causal, kv_offset=off)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_dtypes(dtype):
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.standard_normal((2, 128, 64)), dtype)
-    k = jnp.asarray(rng.standard_normal((2, 128, 64)), dtype)
-    v = jnp.asarray(rng.standard_normal((2, 128, 64)), dtype)
-    out_k = flash_attention(q, k, v, causal=True)
-    out_r = ref.flash_attention_ref(q, k, v, causal=True)
-    tol = 2e-4 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out_k, np.float32),
-                               np.asarray(out_r, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_flash_attention_batched_vmap():
-    rng = np.random.default_rng(2)
-    q = jnp.asarray(rng.standard_normal((3, 4, 128, 64)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((3, 2, 128, 64)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((3, 2, 128, 64)), jnp.float32)
-    f = lambda q, k, v: flash_attention(q, k, v, causal=True)
-    out_k = jax.vmap(f)(q, k, v)
-    out_r = jax.vmap(lambda q, k, v: ref.flash_attention_ref(
-        q, k, v, causal=True))(q, k, v)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("v,d,b,l", [
-    (64, 32, 16, 4), (256, 128, 32, 8), (1000, 64, 8, 12)])
-def test_embedding_bag_matches_ref(v, d, b, l):
-    rng = np.random.default_rng(v + b)
-    table = jnp.asarray(rng.standard_normal((v, d)), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, v, (b, l)), jnp.int32)
-    w = jnp.asarray(rng.random((b, l)), jnp.float32)
-    out_k = embedding_bag(table, idx, w, block_b=min(8, b))
-    out_r = ref.embedding_bag_ref(table, idx, w)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_embedding_bag_padding_weights():
-    table = jnp.asarray(np.eye(8, 4), jnp.float32)
-    idx = jnp.asarray([[1, 2, 0], [3, 0, 0]], jnp.int32)
-    w = jnp.asarray([[1.0, 1.0, 0.0], [2.0, 0.0, 0.0]], jnp.float32)
-    out = embedding_bag(table, idx, w, block_b=2)
-    expect = np.zeros((2, 4), np.float32)
-    expect[0, 1] = 1.0
-    expect[0, 2] = 1.0
-    expect[1, 3] = 2.0
-    np.testing.assert_allclose(np.asarray(out), expect)

@@ -5,38 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import schnet as S
 from repro.models.transformer import (TransformerConfig, decode_step,
                                       init_params, logits_fn, forward,
                                       prefill)
-
-
-def test_schnet_energy_translation_invariant():
-    """SchNet energies depend on distances only: rigid translation of all
-    atom positions must not change the prediction."""
-    cfg = S.SchNetConfig(n_interactions=2, d_hidden=16, n_rbf=24,
-                         cutoff=5.0, n_atom_types=8)
-    params = S.init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    batch = {
-        "z": jnp.asarray(rng.integers(1, 8, (2, 6))),
-        "pos": jnp.asarray(rng.standard_normal((2, 6, 3)), jnp.float32),
-        "edge_src": jnp.asarray(rng.integers(0, 6, (2, 12))),
-        "edge_dst": jnp.asarray(rng.integers(0, 6, (2, 12))),
-    }
-    e1 = S.molecule_energy(cfg, params, batch)
-    shifted = dict(batch, pos=batch["pos"] + jnp.asarray([10., -3., 7.]))
-    e2 = S.molecule_energy(cfg, params, shifted)
-    np.testing.assert_allclose(np.asarray(e1), np.asarray(e2),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_schnet_rbf_cutoff_kills_long_edges():
-    """Edges at the cutoff contribute (numerically) nothing."""
-    from repro.models.schnet import rbf_expand
-    r = rbf_expand(jnp.asarray([0.1, 4.9, 25.0]), 24, 5.0)
-    assert float(r[0].max()) > 0.5
-    assert float(r[2].max()) < 1e-6  # far beyond cutoff
 
 
 def test_lm_greedy_decode_loop_consistency():

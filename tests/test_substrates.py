@@ -1,5 +1,5 @@
 """Substrate tests: optimizer, checkpoint/resume, trainer fault tolerance,
-gradient compression, straggler monitor, collectives, serving engine."""
+gradient compression, collectives, serving engine."""
 import json
 import pathlib
 
@@ -10,7 +10,6 @@ import pytest
 
 from repro.dist.compression import (compress_with_feedback, compression_ratio,
                                     init_error_feedback)
-from repro.dist.straggler import StragglerConfig, StragglerMonitor
 from repro.train import checkpoint
 from repro.train.optimizer import (AdamWConfig, adamw_init, adamw_update,
                                    cosine_schedule, flop_regularizer)
@@ -173,30 +172,6 @@ def test_error_feedback_mean_error_vanishes():
     resid = np.abs(total_true - total_sent).max()
     assert resid < 0.1, resid
     assert compression_ratio(g) > 3.5
-
-
-# -- straggler -------------------------------------------------------------------
-
-def test_straggler_detection_and_rebalance():
-    mon = StragglerMonitor(n_workers=8, microbatches_per_worker=4,
-                           cfg=StragglerConfig(patience=2, evict_after=50))
-    rng = np.random.default_rng(0)
-    for step in range(10):
-        d = rng.normal(1.0, 0.02, 8)
-        d[3] = 3.0  # worker 3 is slow
-        out = mon.report(step, d)
-    assert mon.degraded[3]
-    assert out["assignments"][3] == 2            # relieved
-    assert out["assignments"].sum() == 32        # work conserved
-    assert out["assignments"][np.argmin(d)] >= 4  # fastest picked up slack
-
-
-def test_straggler_eviction_signal():
-    mon = StragglerMonitor(4, 2, StragglerConfig(patience=1, evict_after=5))
-    for step in range(10):
-        d = np.array([1.0, 1.0, 1.0, 9.0])
-        out = mon.report(step, d)
-    assert 3 in out["evict"]
 
 
 # -- collectives (1-device mesh semantics) --------------------------------------
